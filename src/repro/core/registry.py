@@ -473,6 +473,15 @@ class BravoRegistry:
             self.table = _programs().release_by_index(
                 self.table, self.lock_vals, lock_idx, reader_ids, granted)
 
+    def lower_acquire_by_index(self, lock_idx: jax.Array,
+                               reader_ids: jax.Array):
+        """The fused lease-publish program :meth:`acquire_by_index`
+        dispatches, lowered for these operands (``.compile().as_text()``
+        shows what runs on the device); publishes nothing."""
+        with self._mu:
+            return _programs().acquire_by_index.lower(
+                self.table, self.rbias, self.lock_vals, lock_idx, reader_ids)
+
     # ------------------------------------------------------------ the writer
     def revoke(self, h: "RegistryHandle", *, n: Optional[int] = None,
                wait_poll_s: float = 0.0005, max_wait_s: float = 5.0,
@@ -660,7 +669,7 @@ def make_sharded_revoke(mesh, axis=("pod", "data")):
     4 lanes per shard on the 512-chip dry-run topology)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..dist.sharding import hierarchical_psum, shard_map_compat
+    from ..dist.sharding import axis_size, hierarchical_psum, shard_map_compat
 
     axes = (axis,) if isinstance(axis, str) else tuple(axis)
     missing = [a for a in axes if a not in mesh.axis_names]
@@ -673,7 +682,7 @@ def make_sharded_revoke(mesh, axis=("pod", "data")):
         lanes = rbias_shard.shape[0]
         didx = jnp.zeros((), jnp.int32)
         for a in axes:                  # outermost-first flattened shard id
-            didx = didx * jax.lax.psum(1, a) + jax.lax.axis_index(a)
+            didx = didx * axis_size(a) + jax.lax.axis_index(a)
         local = lidx - didx * lanes     # off-shard -> out of range -> no-op
         rb = jnp.where(jnp.arange(lanes) == local, 0, rbias_shard)
         cnt = jnp.sum((table_shard == lid).astype(jnp.int32))

@@ -74,7 +74,8 @@ from jax.sharding import PartitionSpec as P
 __all__ = [
     "MeshRules", "logical_to_spec", "param_specs", "cache_specs",
     "zero1_specs", "batch_spec", "constrain", "constrain_layer_params",
-    "axis_size", "shard_map_compat", "hierarchical_psum",
+    "kv_head_axis", "page_store_specs", "axis_size", "shard_map_compat",
+    "hierarchical_psum",
 ]
 
 
@@ -347,6 +348,26 @@ def cache_specs(cshape: Any, rules: MeshRules, mesh: Mesh,
     return walk(cshape)
 
 
+def kv_head_axis(n_kv_heads: int, mesh: Mesh) -> Optional[str]:
+    """The mesh axis the paged KV store splits its KV heads over:
+    ``"model"`` when it is live and divides them, else ``None`` (the store
+    replicates — e.g. a single MQA KV head serves every head shard)."""
+    if "model" not in mesh.axis_names or mesh.shape["model"] == 1:
+        return None
+    return "model" if n_kv_heads % mesh.shape["model"] == 0 else None
+
+
+def page_store_specs(store: Any, n_kv_heads: int, mesh: Mesh) -> Any:
+    """Specs for the paged KV store (``models.model.init_paged_caches``):
+    pages ``(L, n_pages, ps, KVH, hd)`` and per-page scales ``(L, n_pages,
+    KVH)`` split their KV-head dim per :func:`kv_head_axis`, everything
+    else replicates — the pool is shared by every request, so it never
+    splits over the data axes."""
+    ax = kv_head_axis(n_kv_heads, mesh)
+    return {k: P(None, None, None, ax, None) if x.ndim == 5
+            else P(None, None, ax) for k, x in store.items()}
+
+
 def batch_spec(rules: MeshRules, mesh: Mesh, shape: Sequence[int]) -> P:
     """Spec for a (B, ...) input leaf: batch axes on dim 0, rest replicated."""
     bax = rules.batch_axes(mesh) or None
@@ -355,17 +376,13 @@ def batch_spec(rules: MeshRules, mesh: Mesh, shape: Sequence[int]) -> P:
 
 
 # ---------------------------------------------------------------------------
-# shard_map compatibility (jax.shard_map landed after 0.4.x)
+# shard_map entry point
 # ---------------------------------------------------------------------------
 
 
 def axis_size(name: str):
-    """Size of a mapped mesh axis inside shard_map (jax.lax.axis_size is
-    newer than 0.4.x; psum of 1 is the portable spelling)."""
-    ax = getattr(jax.lax, "axis_size", None)
-    if ax is not None:
-        return ax(name)
-    return jax.lax.psum(1, name)
+    """Size of a mapped mesh axis inside shard_map."""
+    return jax.lax.axis_size(name)
 
 
 def hierarchical_psum(x, axes: Sequence[str]):
@@ -385,16 +402,7 @@ def hierarchical_psum(x, axes: Sequence[str]):
 
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map when available, else the experimental spelling
-    (``check_vma`` was called ``check_rep`` there)."""
-    import inspect
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {"check_vma": check_vma}
-        if "check_vma" not in inspect.signature(sm).parameters:
-            kw = {"check_rep": check_vma}  # pre-rename signature
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=check_vma)
+    """``jax.shard_map`` — the one call site the source lint allows, so
+    every shard_map in the tree goes through here."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

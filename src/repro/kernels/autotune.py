@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import ref
+from .ops import _interpret
 from .paged_attn import _paged_attn_call, _paged_attn_quant_call
 from .paged_chunk_attn import _chunk_attn_call, _chunk_attn_quant_call
 from .quant import quantize_pages
@@ -55,11 +56,7 @@ __all__ = ["sweep", "run", "mode"]
 def mode() -> str:
     """How the kernels execute on this host: ``mosaic`` (compiled, TPU)
     or ``interpret`` (Pallas body in Python — the validation backend)."""
-    return "mosaic" if jax.default_backend() == "tpu" else "interpret"
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return "interpret" if _interpret() else "mosaic"
 
 
 # --------------------------------------------------------------------------

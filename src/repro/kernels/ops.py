@@ -1,8 +1,10 @@
 """Public jit'd wrappers for the table kernels.
 
-On CPU hosts the kernels run in ``interpret=True`` mode (the Pallas body
-executes in Python — the validation path mandated for this container); on
-TPU they compile to Mosaic.
+On TPU the kernels compile to Mosaic.  On the CPU backend they run in
+``interpret=True`` mode (the Pallas body executes as plain JAX ops — the
+validation path of the test suite).  Any other backend raises: these are
+TPU kernels, and silently interpreting them elsewhere would hide the
+device the program was meant to run on.
 """
 
 from __future__ import annotations
@@ -28,7 +30,16 @@ __all__ = ["as_table2d", "revocation_scan", "revocation_poll",
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """False on TPU (Mosaic), True on the CPU validation backend; an error
+    on any other backend."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas TPU kernels run compiled on a TPU or interpreted on "
+        f"the CPU backend; the default backend is {backend!r}")
 
 
 # --------------------------------------------------------------------------

@@ -44,10 +44,12 @@ def _make_paged_attn_kernel(lanes_per_step: int, quantized: bool):
     lanes each grid step consumes — every lane is its own scalar-prefetched
     (1, ps, KVH, hd) block, so a step with k lanes has k independent DMAs
     in flight instead of one per step.  ``quantized``: the page blocks are
-    int8 and each is followed by its (1, KVH) float32 per-page scale block
+    int8 and each is followed by its float32 per-page scale block
     (fetched through the SAME page-index map); dequantization is one cast
     + broadcast multiply at DMA time, inside VMEM — no fp32 copy of any
-    page ever exists outside the kernel."""
+    page ever exists outside the kernel.  (The scales ride as (1, KVH, 1)
+    blocks of an (n_pages, KVH, 1) view: a block must span the array's two
+    minor dims or be (8, 128)-aligned, and (1, KVH) is neither.)"""
     per_lane = 4 if quantized else 2
 
     def kernel(pi_ref, cl_ref, q_ref, *refs):
@@ -84,8 +86,8 @@ def _make_paged_attn_kernel(lanes_per_step: int, quantized: bool):
 
             if quantized:
                 k_ref, v_ref, ks_ref, vs_ref = lane
-                k = k_ref[0].astype(jnp.float32) * ks_ref[0][None, :, None]
-                v = v_ref[0].astype(jnp.float32) * vs_ref[0][None, :, None]
+                k = k_ref[0].astype(jnp.float32) * ks_ref[0][None]
+                v = v_ref[0].astype(jnp.float32) * vs_ref[0][None]
             else:
                 k_ref, v_ref = lane
                 k = k_ref[0].astype(jnp.float32)          # (ps, KVH, hd)
@@ -131,6 +133,9 @@ def _paged_attn_common(q, kv_operands, page_idx, cache_len, interpret,
             [page_idx, jnp.full((b, pad), -1, page_idx.dtype)], axis=1)
         n_p += pad
     quantized = len(kv_operands) == 4
+    if quantized:     # (n_pages, KVH) -> (n_pages, KVH, 1) scale view
+        kv_operands = kv_operands[:2] + tuple(
+            x[:, :, None] for x in kv_operands[2:])
 
     def kv_map(j):
         def m(bi, pi, idx_ref, cl_ref):
@@ -139,7 +144,7 @@ def _paged_attn_common(q, kv_operands, page_idx, cache_len, interpret,
 
     def scale_map(j):
         def m(bi, pi, idx_ref, cl_ref):
-            return (jnp.maximum(idx_ref[bi, pi * lps + j], 0), 0)
+            return (jnp.maximum(idx_ref[bi, pi * lps + j], 0), 0, 0)
         return m
 
     in_specs = [pl.BlockSpec((1, h, hd), lambda bi, pi, idx, cl: (bi, 0, 0))]
@@ -149,8 +154,8 @@ def _paged_attn_common(q, kv_operands, page_idx, cache_len, interpret,
                      pl.BlockSpec((1, ps, kvh, hd), kv_map(j))]
         operands += [kv_operands[0], kv_operands[1]]
         if quantized:
-            in_specs += [pl.BlockSpec((1, kvh), scale_map(j)),
-                         pl.BlockSpec((1, kvh), scale_map(j))]
+            in_specs += [pl.BlockSpec((1, kvh, 1), scale_map(j)),
+                         pl.BlockSpec((1, kvh, 1), scale_map(j))]
             operands += [kv_operands[2], kv_operands[3]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(

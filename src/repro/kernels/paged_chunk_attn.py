@@ -19,6 +19,10 @@ Layout and grid
   page lanes: TPU grid steps run sequentially on a core, so the per-(row,
   q-block) softmax state (m/l/acc scratch) accumulates across the ``P``
   inner steps and the output block is emitted at the last page.
+* queries are transposed head-major, ``(B, H, S, hd)``, around the call,
+  so each block's matmuls batch over the leading KV-head dim.  A
+  token-major ``(bq, H, hd)`` block made Mosaic unroll the matmuls per
+  (row, head): minutes of compile at 36 heads, against seconds now.
 * ``page_idx``/``cache_len``/``new_lens`` ride in as **scalar-prefetch**
   operands (``PrefetchScalarGridSpec``): the index map reads
   ``page_idx[b, p]`` to pick which page tile the next grid step DMAs — the
@@ -51,9 +55,17 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _pick_block_q(s: int, limit: int = 32) -> int:
-    """Largest divisor of ``s`` that is <= ``limit`` (the VMEM-friendly
-    q-block height); always a divisor — 1 at worst, for prime widths."""
-    for bq in range(min(s, limit), 0, -1):
+    """The q-block height: the largest divisor of ``s`` that is <= ``limit``
+    and a multiple of 8 (the TPU sublane tile), or ``s`` itself when
+    ``s <= limit``; failing both, the largest divisor <= ``limit`` (1 at
+    worst, for prime widths — such a block only lowers in interpret
+    mode)."""
+    if s <= limit:
+        return s
+    for bq in range(limit - limit % 8, 0, -8):
+        if s % bq == 0:
+            return bq
+    for bq in range(limit, 0, -1):
         if s % bq == 0:
             return bq
     raise AssertionError(s)          # unreachable: 1 divides everything
@@ -61,10 +73,15 @@ def _pick_block_q(s: int, limit: int = 32) -> int:
 
 def _make_chunk_attn_kernel(quantized: bool):
     """Kernel factory.  ``quantized``: the page blocks are int8 and each is
-    followed by its (1, KVH) float32 per-page scale block (fetched through
-    the SAME page-index map); dequantization is one cast + broadcast
-    multiply at DMA time, inside VMEM — no fp32 copy of any page ever
-    exists outside the kernel."""
+    followed by its (1, KVH, 1) float32 per-page scale block (fetched
+    through the SAME page-index map); dequantization is one cast +
+    broadcast multiply at DMA time, inside VMEM — no fp32 copy of any page
+    ever exists outside the kernel.
+
+    Queries arrive head-major, ``(H, bq, hd)`` per block, so every matmul
+    is one batched dot over the KV heads with the batch dim leading: the
+    query-block rows of a KV head's ``g`` grouped heads stack into one
+    ``(g * bq, hd)`` operand."""
 
     def kernel(pi_ref, cl_ref, nl_ref, q_ref, *refs):
         if quantized:
@@ -84,7 +101,7 @@ def _make_chunk_attn_kernel(quantized: bool):
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
         ps, kvh, hd = k_ref.shape[1], k_ref.shape[2], k_ref.shape[3]
-        bq, h = q_ref.shape[1], q_ref.shape[2]
+        h, bq = q_ref.shape[1], q_ref.shape[2]
         n_q = pl.num_programs(1)
         s_total = bq * n_q
         g = h // kvh
@@ -100,38 +117,38 @@ def _make_chunk_attn_kernel(quantized: bool):
         valid_q = (col >= s_total - nl) & (q_pos >= 0)
         t_pos = p * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
         valid = (t_pos < clen) & (page >= 0) & (t_pos <= q_pos) & valid_q
+        if g > 1:                                          # (g * bq, ps)
+            valid = jnp.concatenate([valid] * g, axis=0)
+        valid = valid[None]                                # (1, g*bq, ps)
 
-        q = q_ref[0].astype(jnp.float32)                   # (bq, H, hd)
+        # (H, bq, hd) -> (KVH, g * bq, hd): heads grouped by their kv head
+        qh = q_ref[0].astype(jnp.float32).reshape(kvh, g * bq, hd)
         if quantized:
-            k = k_ref[0].astype(jnp.float32) * ks_ref[0][None, :, None]
-            v = v_ref[0].astype(jnp.float32) * vs_ref[0][None, :, None]
+            k = k_ref[0].astype(jnp.float32) * ks_ref[0][None]
+            v = v_ref[0].astype(jnp.float32) * vs_ref[0][None]
         else:
             k = k_ref[0].astype(jnp.float32)               # (ps, KVH, hd)
             v = v_ref[0].astype(jnp.float32)
-        qh = q.reshape(bq, kvh, g, hd)                     # heads grouped by
-        s = jnp.einsum("qkgd,skd->qkgs", qh, k,            # their kv head
+        s = jnp.einsum("kqd,skd->kqs", qh, k,
                        preferred_element_type=jnp.float32) * scale
-        s = s.reshape(bq, h, ps)
-        s = jnp.where(valid[:, None, :], s, -jnp.inf)
+        s = jnp.where(valid, s, -jnp.inf)                  # (KVH, g*bq, ps)
 
-        m_prev = m_ref[...]                                # (bq, H)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2))
+        m_prev = m_ref[...]                                # (KVH, g*bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        pexp = jnp.where(valid[:, None, :],
-                         jnp.exp(s - m_safe[:, :, None]), 0.0)  # (bq, H, ps)
+        pexp = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
         corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(pexp, axis=2)
-        pv = jnp.einsum("qkgs,skd->qkgd", pexp.reshape(bq, kvh, g, ps), v,
+        l_ref[...] = l_ref[...] * corr + jnp.sum(pexp, axis=2, keepdims=True)
+        pv = jnp.einsum("kqs,skd->kqd", pexp, v,
                         preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr[:, :, None] \
-            + pv.reshape(bq, h, hd)
+        acc_ref[...] = acc_ref[...] * corr + pv
         m_ref[...] = m_new
 
         @pl.when(p == n_p - 1)
         def _emit():
             l = jnp.maximum(l_ref[...], 1e-20)             # fully-masked rows
-            o_ref[0] = (acc_ref[...] / l[:, :, None]).astype(o_ref.dtype)
-            #                                                (padding) emit 0
+            o_ref[0] = (acc_ref[...] / l).reshape(h, bq, hd).astype(
+                o_ref.dtype)                               # (padding) emit 0
     return kernel
 
 
@@ -143,45 +160,54 @@ def _chunk_attn_common(q, kv_operands, page_idx, cache_len, new_lens,
     _, ps, kvh, _ = kv_operands[0].shape
     n_p = page_idx.shape[1]
     assert h % kvh == 0, (h, kvh)
+    g = h // kvh
     bq = block_q or _pick_block_q(s)
     assert s % bq == 0, (s, bq)
     n_q = s // bq
     quantized = len(kv_operands) == 4
+    if quantized:     # (n_pages, KVH) -> (n_pages, KVH, 1): a (1, KVH, 1)
+        #               block spans the array's two minor dims, as the TPU
+        #               tiling rule requires
+        kv_operands = kv_operands[:2] + tuple(
+            x[:, :, None] for x in kv_operands[2:])
 
     def kv_map(bi, qi, p, idx_ref, cl_ref, nl_ref):
         return (jnp.maximum(idx_ref[bi, p], 0), 0, 0, 0)
 
     def scale_map(bi, qi, p, idx_ref, cl_ref, nl_ref):
-        return (jnp.maximum(idx_ref[bi, p], 0), 0)
+        return (jnp.maximum(idx_ref[bi, p], 0), 0, 0)
 
     def q_map(bi, qi, p, idx_ref, cl_ref, nl_ref):
-        return (bi, qi, 0, 0)
+        return (bi, 0, qi, 0)
 
-    in_specs = [pl.BlockSpec((1, bq, h, hd), q_map),
+    in_specs = [pl.BlockSpec((1, h, bq, hd), q_map),
                 pl.BlockSpec((1, ps, kvh, hd), kv_map),
                 pl.BlockSpec((1, ps, kvh, hd), kv_map)]
     if quantized:
-        in_specs += [pl.BlockSpec((1, kvh), scale_map),
-                     pl.BlockSpec((1, kvh), scale_map)]
+        in_specs += [pl.BlockSpec((1, kvh, 1), scale_map),
+                     pl.BlockSpec((1, kvh, 1), scale_map)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,            # page_idx, cache_len, new_lens
         grid=(b, n_q, n_p),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bq, h, hd), q_map),
+        out_specs=pl.BlockSpec((1, h, bq, hd), q_map),
         scratch_shapes=[
-            pltpu.VMEM((bq, h), jnp.float32),      # running max
-            pltpu.VMEM((bq, h), jnp.float32),      # running denominator
-            pltpu.VMEM((bq, h, hd), jnp.float32),  # output accumulator
+            pltpu.VMEM((kvh, g * bq, 1), jnp.float32),   # running max
+            pltpu.VMEM((kvh, g * bq, 1), jnp.float32),   # running denominator
+            pltpu.VMEM((kvh, g * bq, hd), jnp.float32),  # output accumulator
         ],
     )
-    return pl.pallas_call(
+    # head-major queries: (B, S, H, hd) -> (B, H, S, hd) and back — an XLA
+    # transpose of the chunk's activations, never of the page store
+    out = pl.pallas_call(
         _make_chunk_attn_kernel(quantized),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, s, h, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, s, hd), q.dtype),
         interpret=interpret,
     )(page_idx.astype(jnp.int32), cache_len.astype(jnp.int32),
-      new_lens.astype(jnp.int32), q, *kv_operands)
+      new_lens.astype(jnp.int32), q.transpose(0, 2, 1, 3), *kv_operands)
+    return out.transpose(0, 2, 1, 3)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_q"))

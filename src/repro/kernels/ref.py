@@ -153,11 +153,12 @@ def paged_chunk_attn_ref(q: jax.Array, k_pages: jax.Array,
             col = qi * bq + jnp.arange(bq)[:, None]            # (bq, 1)
             q_pos = cache_len[bi] - s + col
             valid_q = (col >= s - new_lens[bi]) & (q_pos >= 0)
-            qh = q[bi, qi * bq:(qi + 1) * bq].astype(
-                jnp.float32).reshape(bq, kvh, g, hd)
-            m = jnp.full((bq, h), -jnp.inf, jnp.float32)
-            den = jnp.zeros((bq, h), jnp.float32)
-            acc = jnp.zeros((bq, h, hd), jnp.float32)
+            # head-major block, heads grouped by kv head: (KVH, g*bq, hd)
+            qh = q[bi, qi * bq:(qi + 1) * bq].astype(jnp.float32) \
+                .transpose(1, 0, 2).reshape(kvh, g * bq, hd)
+            m = jnp.full((kvh, g * bq, 1), -jnp.inf, jnp.float32)
+            den = jnp.zeros((kvh, g * bq, 1), jnp.float32)
+            acc = jnp.zeros((kvh, g * bq, hd), jnp.float32)
             for p in range(n_p):
                 page = page_idx[bi, p]
                 k = k_pages[jnp.clip(page, 0)].astype(jnp.float32)
@@ -165,22 +166,21 @@ def paged_chunk_attn_ref(q: jax.Array, k_pages: jax.Array,
                 t_pos = p * ps + jnp.arange(ps)[None, :]       # (1, ps)
                 valid = (t_pos < cache_len[bi]) & (page >= 0) \
                     & (t_pos <= q_pos) & valid_q
-                sc = jnp.einsum("qkgd,skd->qkgs", qh, k,
+                valid = jnp.concatenate([valid] * g, axis=0)[None]
+                sc = jnp.einsum("kqd,skd->kqs", qh, k,
                                 preferred_element_type=jnp.float32) * scale
-                sc = jnp.where(valid[:, None, :],
-                               sc.reshape(bq, h, ps), -jnp.inf)
-                m_new = jnp.maximum(m, jnp.max(sc, axis=2))
+                sc = jnp.where(valid, sc, -jnp.inf)
+                m_new = jnp.maximum(m, jnp.max(sc, axis=2, keepdims=True))
                 m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-                pexp = jnp.where(valid[:, None, :],
-                                 jnp.exp(sc - m_safe[:, :, None]), 0.0)
+                pexp = jnp.where(valid, jnp.exp(sc - m_safe), 0.0)
                 corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
-                den = den * corr + jnp.sum(pexp, axis=2)
-                pv = jnp.einsum("qkgs,skd->qkgd",
-                                pexp.reshape(bq, kvh, g, ps), v,
+                den = den * corr + jnp.sum(pexp, axis=2, keepdims=True)
+                pv = jnp.einsum("kqs,skd->kqd", pexp, v,
                                 preferred_element_type=jnp.float32)
-                acc = acc * corr[:, :, None] + pv.reshape(bq, h, hd)
+                acc = acc * corr + pv
                 m = m_new
-            rows.append(acc / jnp.maximum(den, 1e-20)[:, :, None])
+            out = acc / jnp.maximum(den, 1e-20)
+            rows.append(out.reshape(h, bq, hd).transpose(1, 0, 2))
         outs.append(jnp.concatenate(rows, axis=0))
     return jnp.stack(outs).astype(q.dtype)
 
@@ -260,11 +260,12 @@ def paged_chunk_attn_quant_ref(q: jax.Array, k_pages: jax.Array,
             col = qi * bq + jnp.arange(bq)[:, None]            # (bq, 1)
             q_pos = cache_len[bi] - s + col
             valid_q = (col >= s - new_lens[bi]) & (q_pos >= 0)
-            qh = q[bi, qi * bq:(qi + 1) * bq].astype(
-                jnp.float32).reshape(bq, kvh, g, hd)
-            m = jnp.full((bq, h), -jnp.inf, jnp.float32)
-            den = jnp.zeros((bq, h), jnp.float32)
-            acc = jnp.zeros((bq, h, hd), jnp.float32)
+            # head-major block, heads grouped by kv head: (KVH, g*bq, hd)
+            qh = q[bi, qi * bq:(qi + 1) * bq].astype(jnp.float32) \
+                .transpose(1, 0, 2).reshape(kvh, g * bq, hd)
+            m = jnp.full((kvh, g * bq, 1), -jnp.inf, jnp.float32)
+            den = jnp.zeros((kvh, g * bq, 1), jnp.float32)
+            acc = jnp.zeros((kvh, g * bq, hd), jnp.float32)
             for p in range(n_p):
                 page = page_idx[bi, p]
                 k = deq(k_pages, k_scale, page)
@@ -272,22 +273,21 @@ def paged_chunk_attn_quant_ref(q: jax.Array, k_pages: jax.Array,
                 t_pos = p * ps + jnp.arange(ps)[None, :]       # (1, ps)
                 valid = (t_pos < cache_len[bi]) & (page >= 0) \
                     & (t_pos <= q_pos) & valid_q
-                sc = jnp.einsum("qkgd,skd->qkgs", qh, k,
+                valid = jnp.concatenate([valid] * g, axis=0)[None]
+                sc = jnp.einsum("kqd,skd->kqs", qh, k,
                                 preferred_element_type=jnp.float32) * scale
-                sc = jnp.where(valid[:, None, :],
-                               sc.reshape(bq, h, ps), -jnp.inf)
-                m_new = jnp.maximum(m, jnp.max(sc, axis=2))
+                sc = jnp.where(valid, sc, -jnp.inf)
+                m_new = jnp.maximum(m, jnp.max(sc, axis=2, keepdims=True))
                 m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-                pexp = jnp.where(valid[:, None, :],
-                                 jnp.exp(sc - m_safe[:, :, None]), 0.0)
+                pexp = jnp.where(valid, jnp.exp(sc - m_safe), 0.0)
                 corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
-                den = den * corr + jnp.sum(pexp, axis=2)
-                pv = jnp.einsum("qkgs,skd->qkgd",
-                                pexp.reshape(bq, kvh, g, ps), v,
+                den = den * corr + jnp.sum(pexp, axis=2, keepdims=True)
+                pv = jnp.einsum("kqs,skd->kqd", pexp, v,
                                 preferred_element_type=jnp.float32)
-                acc = acc * corr[:, :, None] + pv.reshape(bq, h, hd)
+                acc = acc * corr + pv
                 m = m_new
-            rows.append(acc / jnp.maximum(den, 1e-20)[:, :, None])
+            out = acc / jnp.maximum(den, 1e-20)
+            rows.append(out.reshape(h, bq, hd).transpose(1, 0, 2))
         outs.append(jnp.concatenate(rows, axis=0))
     return jnp.stack(outs).astype(q.dtype)
 

@@ -14,28 +14,32 @@ Two generations live here:
     copied input -> output on every call.
 
 ``_fused_publish_call`` (the device-BRAVO hot path)
-    Fully vectorized one-hot formulation: gather the current slot values
-    with two one-hot matmuls, resolve in-batch collisions with a
-    first-occurrence mask (exactly sequential-CAS semantics, including
-    duplicate slots), and scatter the winners back as a rank-1-per-request
-    matmul update.  The publish + rbias-recheck + conditional-undo of paper
-    Listing 1 lines 14-22 are fused into the one kernel: the undo branch
-    lowers to masking the update delta with ``rbias != 0``.  The table
-    block is donated via ``input_output_aliases={0: 0}`` so the 16KB table
-    is updated in place instead of copied per call; ``unconditional=True``
-    is the release path (store ``ids`` regardless of occupancy — with 0 ids
-    that clears the slots).
+    One request per ``fori_loop`` step, each step a whole-tile vector
+    update: the request's slot becomes a one-hot mask over the (rows, 128)
+    table tile, its occupancy and in-batch collisions (an earlier request
+    that already claimed the slot) are masked sums reduced to SMEM scalars,
+    and the winner's id lands with one ``where`` — exactly sequential-CAS
+    semantics, duplicate slots included.  Per-request operands (slots, ids,
+    bias) and the granted flags are SMEM scalars; no vector is narrower
+    than the tile and nothing runs on the MXU.  The publish +
+    rbias-recheck + conditional-undo of paper Listing 1 lines 14-22 are
+    fused into the one kernel: the undo branch lowers to masking the store
+    with ``rbias != 0``.  The table block is donated via
+    ``input_output_aliases={0: 0}`` so the 16KB table is updated in place
+    instead of copied per call; ``unconditional=True`` is the release path
+    (store ``ids`` regardless of occupancy — with 0 ids that clears the
+    slots).
 
 ``_fused_publish_multi_call`` (the multi-lock registry hot path)
-    Same one-hot publish, but the scalar rbias operand becomes the
-    registry's *per-lock bias vector* and each request carries a lock
-    index: the kernel gathers ``rbias[lock_idx]`` with a (M, L) one-hot
-    inside the program, so one dispatch can publish leases for requests
-    spanning many locks and the recheck/undo applies per request — a
-    revoked lock's requests are undone while every other lock's requests
-    land.  An unbiased request never attempts its CAS, so (matching the
-    sequential semantics where a fast path not taken leaves the slot free)
-    it does not shadow a later in-batch request for the same slot.
+    Same publish loop, but the scalar rbias operand becomes the registry's
+    *per-lock bias vector* and each request carries a lock index: the
+    kernel reads ``rbias[lock_idx]`` from SMEM inside the program, so one
+    dispatch can publish leases for requests spanning many locks and the
+    recheck/undo applies per request — a revoked lock's requests are
+    undone while every other lock's requests land.  An unbiased request
+    never attempts its CAS, so (matching the sequential semantics where a
+    fast path not taken leaves the slot free) it does not shadow a later
+    in-batch request for the same slot.
 """
 
 from __future__ import annotations
@@ -45,6 +49,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .table_scan import LANES
 
@@ -58,15 +63,14 @@ def _publish_kernel(table_ref, slots_ref, ids_ref, out_table_ref,
         slot = slots_ref[0, i]
         row = slot // LANES
         col = slot % LANES
-        cur = pl.load(out_table_ref, (pl.ds(row, 1), pl.ds(col, 1)))[0, 0]
+        cur = out_table_ref[pl.ds(row, 1), pl.ds(col, 1)][0, 0]
         val = ids_ref[0, i]
         if unconditional:
             ok = jnp.bool_(True)
         else:
             ok = cur == 0
         new = jnp.where(ok, val, cur)
-        pl.store(out_table_ref, (pl.ds(row, 1), pl.ds(col, 1)),
-                 new.reshape(1, 1))
+        out_table_ref[pl.ds(row, 1), pl.ds(col, 1)] = new.reshape(1, 1)
         granted_ref[0, i] = ok.astype(jnp.int8)
         return 0
 
@@ -108,53 +112,67 @@ def _publish_call(table2d: jax.Array, slots: jax.Array, ids: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+def _publish_loop(table_ref, out_table_ref, granted_ref, slots_ref, ids_ref,
+                  attempts, *, check_free: bool):
+    """Sequential-CAS semantics over the request batch, one request per
+    loop step.  Per-request operands are SMEM scalars; the table is one
+    (rows, LANES) VMEM tile and every vector is that tile's shape.
+
+    Request ``i`` wins iff no EARLIER attempting request targeted its slot
+    (``claimed``) and — unless ``check_free`` is off (release / forced
+    store) — the slot was free in the incoming table.  ``attempts(i)`` is
+    the scalar "this request tries its CAS" predicate (the rbias recheck
+    of paper Listing 1 lines 14-22; a request whose fast path is off never
+    CASes, so it neither wins nor shadows a later request)."""
+    table = table_ref[...]                       # (rows, LANES) int32
+    rows = table.shape[0]
+    pos = (jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 0) * LANES
+           + jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 1))
+
+    def body(i, carry):
+        new, claimed = carry
+        hit = pos == slots_ref[i]                # (rows, LANES) one-hot
+        taken = jnp.sum(jnp.where(hit, claimed, 0))
+        ok = (taken == 0) & attempts(i)
+        if check_free:
+            ok = ok & (jnp.sum(jnp.where(hit, table, 0)) == 0)
+        win = ok.astype(jnp.int32)
+        new = jnp.where(hit & (win != 0), ids_ref[i], new)
+        claimed = jnp.where(hit & attempts(i), 1, claimed)
+        granted_ref[i] = win
+        return new, claimed
+
+    new, _ = jax.lax.fori_loop(
+        0, slots_ref.shape[0], body, (table, jnp.zeros_like(table)))
+    out_table_ref[...] = new
+
+
 def _fused_publish_kernel(table_ref, rbias_ref, slots_ref, ids_ref,
                           out_table_ref, granted_ref, *,
                           unconditional: bool, check_rbias: bool):
-    table = table_ref[...]                       # (rows, LANES) int32
-    rows = table.shape[0]
-    slots = slots_ref[0, :]                      # (M,) int32
-    ids = ids_ref[0, :]
-    m = slots.shape[0]
-    r_idx = slots // LANES
-    c_idx = slots % LANES
+    # publish + recheck-rbias + conditional undo (Listing 1 lines 14-22),
+    # fused: an undone publish is a publish whose store never lands, so
+    # the winners are masked with the bias flag read *in kernel*
+    biased = rbias_ref[0] != 0
 
-    # one-hot row/col selectors; each request is a rank-1 (row x col) update
-    oh_r = (r_idx[:, None]
-            == jax.lax.broadcasted_iota(jnp.int32, (m, rows), 1)
-            ).astype(jnp.int32)                  # (M, rows)
-    oh_c = (c_idx[:, None]
-            == jax.lax.broadcasted_iota(jnp.int32, (m, LANES), 1)
-            ).astype(jnp.int32)                  # (M, LANES)
+    def attempts(i):
+        return biased if check_rbias else jnp.bool_(True)
 
-    # sequential-CAS collision semantics: first request per slot wins
-    order = jax.lax.broadcasted_iota(jnp.int32, (m, m), 0)   # row = request
-    dup_earlier = (slots[None, :] == slots[:, None]) \
-        & (order.T < order)                      # [i, j]: j < i, same slot
-    first = ~jnp.any(dup_earlier, axis=1)        # (M,)
+    _publish_loop(table_ref, out_table_ref, granted_ref, slots_ref, ids_ref,
+                  attempts, check_free=not unconditional)
 
-    if unconditional:
-        win = first                              # release / forced store
-    else:
-        # current occupancy, gathered via the same one-hots (VPU/MXU only,
-        # no per-request dynamic loads)
-        cur = jnp.sum(jnp.dot(oh_r, table) * oh_c, axis=1)   # (M,)
-        win = first & (cur == 0)
 
-    if check_rbias:
-        # publish + recheck-rbias + conditional undo (Listing 1 lines
-        # 14-22), fused: an undone publish is a publish whose delta never
-        # lands, so mask the winners with the bias flag read *in kernel*.
-        win = win & (rbias_ref[0, 0] != 0)
-
-    winv = win.astype(jnp.int32)
-    delta = jnp.dot((oh_r * winv[:, None]).T, oh_c * ids[:, None])
-    if unconditional:
-        occ = jnp.dot((oh_r * winv[:, None]).T, oh_c)        # 0/1: touched
-        out_table_ref[...] = table * (1 - occ) + delta
-    else:
-        out_table_ref[...] = table + delta       # winners hit free slots
-    granted_ref[0, :] = win.astype(jnp.int8)
+def _publish_specs(n_scalar_operands: int) -> dict:
+    """The table tile in VMEM (operand 0, aliased onto output 0); every
+    per-request operand and the granted vector in SMEM."""
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    return dict(
+        grid=(1,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)]
+        + [smem] * n_scalar_operands,
+        out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM), smem],
+        input_output_aliases={0: 0},     # table updated in place, no copy
+    )
 
 
 @functools.partial(jax.jit,
@@ -173,27 +191,15 @@ def _fused_publish_call(table2d: jax.Array, rbias: jax.Array,
                              check_rbias=check_rbias)
     table_out, granted = pl.pallas_call(
         kern,
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec((rows, LANES), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, m), lambda i: (0, 0)),
-            pl.BlockSpec((1, m), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((rows, LANES), lambda i: (0, 0)),
-            pl.BlockSpec((1, m), lambda i: (0, 0)),
-        ],
         out_shape=[
             jax.ShapeDtypeStruct((rows, LANES), table2d.dtype),
-            jax.ShapeDtypeStruct((1, m), jnp.int8),
+            jax.ShapeDtypeStruct((m,), jnp.int32),
         ],
-        input_output_aliases={0: 0},     # table updated in place, no copy
         interpret=interpret,
-    )(table2d, rbias.reshape(1, 1).astype(jnp.int32),
-      slots.reshape(1, m).astype(jnp.int32),
-      ids.reshape(1, m).astype(table2d.dtype))
-    return table_out, granted[0].astype(jnp.bool_)
+        **_publish_specs(3),
+    )(table2d, rbias.reshape(1).astype(jnp.int32), slots.astype(jnp.int32),
+      ids.astype(table2d.dtype))
+    return table_out, granted != 0
 
 
 # ---------------------------------------------------------------------------
@@ -203,45 +209,13 @@ def _fused_publish_call(table2d: jax.Array, rbias: jax.Array,
 
 def _fused_publish_multi_kernel(table_ref, rbias_ref, slots_ref, lidx_ref,
                                 ids_ref, out_table_ref, granted_ref):
-    table = table_ref[...]                       # (rows, LANES) int32
-    rows = table.shape[0]
-    slots = slots_ref[0, :]                      # (M,) int32
-    lidx = lidx_ref[0, :]                        # (M,) int32, in [0, L)
-    ids = ids_ref[0, :]
-    m = slots.shape[0]
-    n_locks = rbias_ref.shape[1]
-    r_idx = slots // LANES
-    c_idx = slots % LANES
+    # per-request bias: rbias[lock_idx] gathered from SMEM — the registry's
+    # per-lock recheck, in kernel (no host rbias read)
+    def attempts(i):
+        return rbias_ref[lidx_ref[i]] != 0
 
-    # per-request bias: gather rbias[lock_idx] via a (M, L) one-hot — the
-    # registry's per-lock recheck, in kernel (no host rbias read)
-    oh_l = (lidx[:, None]
-            == jax.lax.broadcasted_iota(jnp.int32, (m, n_locks), 1)
-            ).astype(jnp.int32)                  # (M, L)
-    rb_ok = jnp.sum(oh_l * rbias_ref[0, :][None, :], axis=1) != 0   # (M,)
-
-    oh_r = (r_idx[:, None]
-            == jax.lax.broadcasted_iota(jnp.int32, (m, rows), 1)
-            ).astype(jnp.int32)                  # (M, rows)
-    oh_c = (c_idx[:, None]
-            == jax.lax.broadcasted_iota(jnp.int32, (m, LANES), 1)
-            ).astype(jnp.int32)                  # (M, LANES)
-
-    # sequential-CAS collision semantics among *attempting* requests only:
-    # an unbiased request never CASes, so it must not shadow a later
-    # in-batch request for the same slot
-    order = jax.lax.broadcasted_iota(jnp.int32, (m, m), 0)   # row = request
-    dup_earlier = (slots[None, :] == slots[:, None]) \
-        & (order.T < order) & rb_ok[None, :]     # [i, j]: j < i attempted
-    first = ~jnp.any(dup_earlier, axis=1)        # (M,)
-
-    cur = jnp.sum(jnp.dot(oh_r, table) * oh_c, axis=1)       # (M,) occupancy
-    win = first & (cur == 0) & rb_ok
-
-    winv = win.astype(jnp.int32)
-    delta = jnp.dot((oh_r * winv[:, None]).T, oh_c * ids[:, None])
-    out_table_ref[...] = table + delta           # winners hit free slots
-    granted_ref[0, :] = win.astype(jnp.int8)
+    _publish_loop(table_ref, out_table_ref, granted_ref, slots_ref, ids_ref,
+                  attempts, check_free=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -255,29 +229,14 @@ def _fused_publish_multi_call(table2d: jax.Array, rbias_vec: jax.Array,
     rows, lanes = table2d.shape
     assert lanes == LANES, table2d.shape
     m = slots.shape[0]
-    n_locks = rbias_vec.shape[0]
     table_out, granted = pl.pallas_call(
         _fused_publish_multi_kernel,
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec((rows, LANES), lambda i: (0, 0)),
-            pl.BlockSpec((1, n_locks), lambda i: (0, 0)),
-            pl.BlockSpec((1, m), lambda i: (0, 0)),
-            pl.BlockSpec((1, m), lambda i: (0, 0)),
-            pl.BlockSpec((1, m), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((rows, LANES), lambda i: (0, 0)),
-            pl.BlockSpec((1, m), lambda i: (0, 0)),
-        ],
         out_shape=[
             jax.ShapeDtypeStruct((rows, LANES), table2d.dtype),
-            jax.ShapeDtypeStruct((1, m), jnp.int8),
+            jax.ShapeDtypeStruct((m,), jnp.int32),
         ],
-        input_output_aliases={0: 0},     # table updated in place, no copy
         interpret=interpret,
-    )(table2d, rbias_vec.reshape(1, n_locks).astype(jnp.int32),
-      slots.reshape(1, m).astype(jnp.int32),
-      lock_idx.reshape(1, m).astype(jnp.int32),
-      ids.reshape(1, m).astype(table2d.dtype))
-    return table_out, granted[0].astype(jnp.bool_)
+        **_publish_specs(4),
+    )(table2d, rbias_vec.astype(jnp.int32), slots.astype(jnp.int32),
+      lock_idx.astype(jnp.int32), ids.astype(table2d.dtype))
+    return table_out, granted != 0

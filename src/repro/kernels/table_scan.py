@@ -10,7 +10,9 @@ about).
 Layout: the table is shaped (rows, 128) int32 — 128 lanes per VPU register
 row; block = (BLOCK_ROWS, 128) tiles.  Outputs: a per-slot match mask (int8)
 and the total match count (accumulated across sequential grid steps, as TPU
-grid iterations execute in order on a core).
+grid iterations execute in order on a core).  Lock values arrive as
+scalar-prefetch operands and counts are SMEM scalars: Mosaic keeps scalars
+in SMEM and vectors in VMEM tiles, never a scalar store into a VMEM tile.
 """
 
 from __future__ import annotations
@@ -20,21 +22,29 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 BLOCK_ROWS = 8
 
 
 def _scan_kernel(lock_ref, table_ref, mask_ref, count_ref):
-    blk = table_ref[...]                       # (BLOCK_ROWS, 128) int32
-    m = (blk == lock_ref[0, 0])
+    """``lock_ref`` (1,) and ``count_ref`` (1,) live in SMEM: the count is
+    a scalar accumulated across the sequential grid steps, and Mosaic
+    stores scalars only to SMEM."""
+    m = table_ref[...] == lock_ref[0]          # (BLOCK_ROWS, 128)
     mask_ref[...] = m.astype(jnp.int8)
 
     @pl.when(pl.program_id(0) == 0)
     def _init():
-        count_ref[0, 0] = 0
+        count_ref[0] = 0
 
-    count_ref[0, 0] += jnp.sum(m.astype(jnp.int32))
+    count_ref[0] += jnp.sum(m.astype(jnp.int32))
+
+
+def _lock_operand(lock_id: jax.Array, dtype) -> jax.Array:
+    """The lock value as a (1,) scalar-prefetch operand (SMEM)."""
+    return jnp.reshape(lock_id.astype(dtype), (1,))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -42,26 +52,23 @@ def _scan_call(table2d: jax.Array, lock_id: jax.Array,
                interpret: bool = False):
     rows, lanes = table2d.shape
     assert lanes == LANES and rows % BLOCK_ROWS == 0, table2d.shape
-    grid = (rows // BLOCK_ROWS,)
-    lock = jnp.reshape(lock_id.astype(table2d.dtype), (1, 1))
     mask, count = pl.pallas_call(
         _scan_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(rows // BLOCK_ROWS,),
+            in_specs=[pl.BlockSpec((BLOCK_ROWS, LANES), lambda i, lk: (i, 0))],
+            out_specs=[
+                pl.BlockSpec((BLOCK_ROWS, LANES), lambda i, lk: (i, 0)),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((rows, LANES), jnp.int8),
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
+            jax.ShapeDtypeStruct((1,), jnp.int32),
         ],
         interpret=interpret,
-    )(lock, table2d)
-    return mask, count[0, 0]
+    )(_lock_operand(lock_id, table2d.dtype), table2d)
+    return mask, count[0]
 
 
 def _poll_kernel(lock_ref, table_ref, count_ref):
@@ -74,12 +81,12 @@ def _poll_kernel(lock_ref, table_ref, count_ref):
     """
     @pl.when(pl.program_id(0) == 0)
     def _init():
-        count_ref[0, 0] = 0
+        count_ref[0] = 0
 
-    @pl.when(count_ref[0, 0] == 0)
+    @pl.when(count_ref[0] == 0)
     def _scan():
         blk = table_ref[...]
-        count_ref[0, 0] = jnp.sum((blk == lock_ref[0, 0]).astype(jnp.int32))
+        count_ref[0] = jnp.sum((blk == lock_ref[0]).astype(jnp.int32))
 
 
 def _multi_poll_kernel(locks_ref, table_ref, counts_ref):
@@ -88,17 +95,22 @@ def _multi_poll_kernel(locks_ref, table_ref, counts_ref):
     The registry drains several locks at once (e.g. freeing a striped KV
     pool) and must poll each lock without disturbing any other lock's bias:
     polling never touches rbias at all, and one streamed pass produces all
-    K counts instead of K scans.  The (rows*LANES, K) compare keeps every
-    intermediate rank-2 for the VPU.
+    K counts instead of K scans.  Each lock value is an SMEM scalar compared
+    against the whole (BLOCK_ROWS, 128) tile, so every vector stays the
+    tile's own rank-2 shape; the K counts accumulate as SMEM scalars.
     """
     @pl.when(pl.program_id(0) == 0)
     def _init():
-        counts_ref[...] = jnp.zeros_like(counts_ref)
+        for j in range(counts_ref.shape[0]):
+            counts_ref[j] = 0
 
     blk = table_ref[...]                       # (BLOCK_ROWS, 128)
-    flat = blk.reshape(-1, 1)                  # (BLOCK_ROWS*128, 1)
-    m = (flat == locks_ref[0, :][None, :])     # (BLOCK_ROWS*128, K)
-    counts_ref[0, :] += jnp.sum(m.astype(jnp.int32), axis=0)
+
+    def body(j, carry):
+        counts_ref[j] += jnp.sum((blk == locks_ref[j]).astype(jnp.int32))
+        return carry
+
+    jax.lax.fori_loop(0, counts_ref.shape[0], body, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -108,20 +120,16 @@ def _multi_poll_call(table2d: jax.Array, lock_ids: jax.Array,
     rows, lanes = table2d.shape
     assert lanes == LANES and rows % BLOCK_ROWS == 0, table2d.shape
     k = lock_ids.shape[0]
-    grid = (rows // BLOCK_ROWS,)
-    locks = jnp.reshape(lock_ids.astype(table2d.dtype), (1, k))
-    counts = pl.pallas_call(
+    return pl.pallas_call(
         _multi_poll_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, k), lambda i: (0, 0)),
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, k), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, k), jnp.int32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(rows // BLOCK_ROWS,),
+            in_specs=[pl.BlockSpec((BLOCK_ROWS, LANES), lambda i, lk: (i, 0))],
+            out_specs=pl.BlockSpec(memory_space=pltpu.SMEM)),
+        out_shape=jax.ShapeDtypeStruct((k,), jnp.int32),
         interpret=interpret,
-    )(locks, table2d)
-    return counts[0, :]
+    )(lock_ids.astype(table2d.dtype), table2d)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -129,17 +137,14 @@ def _poll_call(table2d: jax.Array, lock_id: jax.Array,
                interpret: bool = False) -> jax.Array:
     rows, lanes = table2d.shape
     assert lanes == LANES and rows % BLOCK_ROWS == 0, table2d.shape
-    grid = (rows // BLOCK_ROWS,)
-    lock = jnp.reshape(lock_id.astype(table2d.dtype), (1, 1))
     count = pl.pallas_call(
         _poll_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(rows // BLOCK_ROWS,),
+            in_specs=[pl.BlockSpec((BLOCK_ROWS, LANES), lambda i, lk: (i, 0))],
+            out_specs=pl.BlockSpec(memory_space=pltpu.SMEM)),
+        out_shape=jax.ShapeDtypeStruct((1,), jnp.int32),
         interpret=interpret,
-    )(lock, table2d)
-    return count[0, 0]
+    )(_lock_operand(lock_id, table2d.dtype), table2d)
+    return count[0]

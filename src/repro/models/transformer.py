@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..dist.sharding import axis_size, shard_map_compat
+from ..dist.sharding import axis_size, kv_head_axis, shard_map_compat
 from ..kernels import ops as K
 from .common import (ModelConfig, Params, act_fn, apply_rope, decode_attention,
                      dense_init, flash_attention, flash_attention_kvscan,
@@ -79,118 +79,45 @@ def init_moe(key, cfg: ModelConfig, n: int) -> Params:
 # ---------------------------------------------------------------------------
 
 
-def _paged_attention_sharded(q, k_pages, v_pages, pages, cache_len,
-                             mesh, data_axes):
-    """Decode attention over the page store, wired for multi-host meshes:
-    when the data axes are live and divide the batch, requests shard over
-    them via ``shard_map_compat`` (never raw ``jax.shard_map`` — the pinned
-    jax predates it) and each shard streams only ITS requests' pages
-    through the kernel; the page store replicates (it is the pool)."""
-    b = q.shape[0]
-    if mesh is not None and not getattr(mesh, "empty", False):
-        bax = tuple(a for a in data_axes if a in mesh.axis_names)
-        nb = 1
-        for a in bax:
-            nb *= mesh.shape[a]
-        if nb > 1 and b % nb == 0:
-            def body(q_, pg_, cl_, kp_, vp_):
-                return K.paged_attention(q_, kp_, vp_, pg_, cl_)
+def _paged_kernel_sharded(kernel, q, store, rows, mesh, data_axes):
+    """Run a paged-attention Pallas kernel ``kernel(q, *store, *rows)``
+    on a live mesh.
 
-            return shard_map_compat(
-                body, mesh=mesh,
-                in_specs=(P(bax), P(bax), P(bax), P(), P()),
-                out_specs=P(bax), check_vma=False)(
-                    q, pages, cache_len, k_pages, v_pages)
-    return K.paged_attention(q, k_pages, v_pages, pages, cache_len)
+    A ``pallas_call`` is opaque to the SPMD partitioner, so the layout is
+    explicit, through ``shard_map_compat`` (the tree's one ``jax.shard_map``
+    call site, which the source lint enforces): requests split over the
+    data axes when those divide the batch, and query heads over ``model``.
+    The page store splits its KV heads over ``model`` when they divide
+    evenly; a single KV head (MQA) replicates to every head shard.  Each
+    shard then streams only ITS requests' pages for ITS heads.
 
-
-def _paged_chunk_attention(q, k_pages, v_pages, pages, cache_len, new_lens,
-                           mesh, data_axes):
-    """Chunked-prefill attention: the chunk's right-aligned queries attend
-    causally to every valid position in their request's pages, STREAMED
-    through the ``kernels.paged_chunk_attn`` Pallas kernel — the pages
-    feed the MXU one scalar-prefetched tile at a time, so the dense
-    ``(B, lanes * page_size, KVH, hd)`` gather of the PR-4 path (a full
-    per-request KV materialization per layer per tick) never exists.  The
-    dense formulation survives as ``kernels.ref.paged_chunk_dense_ref``
-    (the allclose cross-check and benchmark baseline).
-
-    Multi-host wiring mirrors :func:`_paged_attention_sharded`: a
-    ``pallas_call`` is opaque to the SPMD partitioner (the dense jnp path
-    partitioned for free; the kernel would replicate), so when the data
-    axes are live and divide the batch the rows shard explicitly via
-    ``shard_map_compat`` and each shard streams only ITS rows' pages; the
-    page store replicates (it is the pool)."""
-    b = q.shape[0]
-    if mesh is not None and not getattr(mesh, "empty", False):
-        bax = tuple(a for a in data_axes if a in mesh.axis_names)
-        nb = 1
-        for a in bax:
-            nb *= mesh.shape[a]
-        if nb > 1 and b % nb == 0:
-            def body(q_, pg_, cl_, nl_, kp_, vp_):
-                return K.paged_chunk_attention(q_, kp_, vp_, pg_, cl_, nl_)
-
-            return shard_map_compat(
-                body, mesh=mesh,
-                in_specs=(P(bax), P(bax), P(bax), P(bax), P(), P()),
-                out_specs=P(bax), check_vma=False)(
-                    q, pages, cache_len, new_lens, k_pages, v_pages)
-    return K.paged_chunk_attention(q, k_pages, v_pages, pages, cache_len,
-                                   new_lens)
-
-
-def _paged_attention_quant_sharded(q, k_pages, v_pages, k_scale, v_scale,
-                                   pages, cache_len, mesh, data_axes):
-    """Quantized-pool decode attention, sharded like
-    :func:`_paged_attention_sharded`; the per-page scales replicate with
-    the page store (they are pool metadata)."""
-    b = q.shape[0]
-    if mesh is not None and not getattr(mesh, "empty", False):
-        bax = tuple(a for a in data_axes if a in mesh.axis_names)
-        nb = 1
-        for a in bax:
-            nb *= mesh.shape[a]
-        if nb > 1 and b % nb == 0:
-            def body(q_, pg_, cl_, kp_, vp_, ks_, vs_):
-                return K.paged_attention_quant(q_, kp_, vp_, ks_, vs_,
-                                               pg_, cl_)
-
-            return shard_map_compat(
-                body, mesh=mesh,
-                in_specs=(P(bax), P(bax), P(bax), P(), P(), P(), P()),
-                out_specs=P(bax), check_vma=False)(
-                    q, pages, cache_len, k_pages, v_pages, k_scale, v_scale)
-    return K.paged_attention_quant(q, k_pages, v_pages, k_scale, v_scale,
-                                   pages, cache_len)
-
-
-def _paged_chunk_attention_quant(q, k_pages, v_pages, k_scale, v_scale,
-                                 pages, cache_len, new_lens, mesh,
-                                 data_axes):
-    """Quantized-pool chunk-prefill attention, sharded like
-    :func:`_paged_chunk_attention`; scales replicate with the store."""
-    b = q.shape[0]
-    if mesh is not None and not getattr(mesh, "empty", False):
-        bax = tuple(a for a in data_axes if a in mesh.axis_names)
-        nb = 1
-        for a in bax:
-            nb *= mesh.shape[a]
-        if nb > 1 and b % nb == 0:
-            def body(q_, pg_, cl_, nl_, kp_, vp_, ks_, vs_):
-                return K.paged_chunk_attention_quant(q_, kp_, vp_, ks_, vs_,
-                                                     pg_, cl_, nl_)
-
-            return shard_map_compat(
-                body, mesh=mesh,
-                in_specs=(P(bax), P(bax), P(bax), P(bax), P(), P(), P(),
-                          P()),
-                out_specs=P(bax), check_vma=False)(
-                    q, pages, cache_len, new_lens, k_pages, v_pages,
-                    k_scale, v_scale)
-    return K.paged_chunk_attention_quant(q, k_pages, v_pages, k_scale,
-                                         v_scale, pages, cache_len,
-                                         new_lens)
+    ``q`` carries its heads at axis -2; ``store`` holds the page-store
+    leaves (pages ``(n_pages, ps, KVH, hd)``, per-page scales ``(n_pages,
+    KVH)``); ``rows`` are per-request operands with the batch leading."""
+    if mesh is None or getattr(mesh, "empty", False):
+        return kernel(q, *store, *rows)
+    b, h, kvh = q.shape[0], q.shape[-2], store[0].shape[2]
+    bax = tuple(a for a in data_axes if a in mesh.axis_names)
+    nb = math.prod(mesh.shape[a] for a in bax)
+    nm = mesh.shape["model"] if "model" in mesh.axis_names else 1
+    if b % nb:
+        bax, nb = (), 1
+    if nb == 1 and nm == 1:
+        return kernel(q, *store, *rows)
+    if h % nm or (kvh % nm and kvh != 1):
+        raise ValueError(
+            f"paged attention splits heads over model={nm}: needs the "
+            f"{h} query heads divisible by it and the {kvh} KV heads "
+            f"divisible by it (or a single KV head)")
+    heads = "model" if nm > 1 else None
+    kv_heads = kv_head_axis(kvh, mesh)
+    q_spec = P(bax or None, *([None] * (q.ndim - 3)), heads, None)
+    store_specs = tuple(P(None, None, kv_heads, None) if x.ndim == 4
+                        else P(None, kv_heads) for x in store)
+    return shard_map_compat(
+        kernel, mesh=mesh,
+        in_specs=(q_spec,) + store_specs + (P(bax or None),) * len(rows),
+        out_specs=q_spec, check_vma=False)(q, *store, *rows)
 
 
 def attn_forward(p: Params, x: jax.Array, cfg: ModelConfig, *,
@@ -210,9 +137,9 @@ def attn_forward(p: Params, x: jax.Array, cfg: ModelConfig, *,
     ``pages`` is each request's (B, P) page-index vector and position ``t``
     lives at ``pages[b, t // page_size]`` offset ``t % page_size``.  The
     chunk's K/V are scattered into the pages in place and attention reads
-    by page index — S == 1 through the streaming Pallas kernel, S > 1
+    by page index — S == 1 through the streaming decode kernel, S > 1
     (chunked prefill, right-aligned with ``new_lens`` valid trailing
-    tokens per row) through the gather-dense chunk path.  A store that
+    tokens per row) through the streaming chunk-prefill kernel.  A store that
     also carries ``k_scale``/``v_scale`` leaves is the QUANTIZED pool
     (int8 pages + per-(page, KV head) float32 scales, ``kernels.quant``):
     writes go through ``requant_scatter`` and attention through the
@@ -237,14 +164,15 @@ def attn_forward(p: Params, x: jax.Array, cfg: ModelConfig, *,
             cache["k"], cache["v"], cache["k_scale"], cache["v_scale"],
             k, v, pages, cache_len, new_lens)
         if S == 1 and new_lens is None:
-            o = _paged_attention_quant_sharded(
-                q[:, 0], kc, vc, ksc, vsc, pages, cache_len,
-                mesh, data_axes)[:, None]
+            o = _paged_kernel_sharded(
+                K.paged_attention_quant, q[:, 0], (kc, vc, ksc, vsc),
+                (pages, cache_len), mesh, data_axes)[:, None]
         else:
             nl = new_lens if new_lens is not None \
                 else jnp.full((B,), S, jnp.int32)
-            o = _paged_chunk_attention_quant(q, kc, vc, ksc, vsc, pages,
-                                             cache_len, nl, mesh, data_axes)
+            o = _paged_kernel_sharded(
+                K.paged_chunk_attention_quant, q, (kc, vc, ksc, vsc),
+                (pages, cache_len, nl), mesh, data_axes)
         new_cache = {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc}
     elif pages is not None:
         # paged data plane: scatter the chunk's K/V into the shared page
@@ -265,13 +193,15 @@ def attn_forward(p: Params, x: jax.Array, cfg: ModelConfig, *,
         vc = cache["v"].at[page, off].set(v.astype(cache["v"].dtype),
                                           mode="drop")
         if S == 1 and new_lens is None:
-            o = _paged_attention_sharded(q[:, 0], kc, vc, pages, cache_len,
-                                         mesh, data_axes)[:, None]
+            o = _paged_kernel_sharded(
+                K.paged_attention, q[:, 0], (kc, vc), (pages, cache_len),
+                mesh, data_axes)[:, None]
         else:
             nl = new_lens if new_lens is not None \
                 else jnp.full((B,), S, jnp.int32)
-            o = _paged_chunk_attention(q, kc, vc, pages, cache_len, nl,
-                                       mesh, data_axes)
+            o = _paged_kernel_sharded(
+                K.paged_chunk_attention, q, (kc, vc), (pages, cache_len, nl),
+                mesh, data_axes)
         new_cache = {"k": kc, "v": vc}
     elif cache is None:
         if seqshard and mesh is not None:

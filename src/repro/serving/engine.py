@@ -59,12 +59,15 @@ from typing import Any, Callable, Dict, List, Optional, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from ..core.atomics import LiveMem
 from ..core.device_bravo import LeaseHandle
 from ..core.errors import DrainTimeout
 from ..core.factory import LockEnv
 from ..core.registry import BravoRegistry, RegistryHandle
+from ..dist.sharding import page_store_specs
 from ..models import model as M
 from ..models.common import ModelConfig
 from ..kernels.quant import quant_layout_tag
@@ -124,6 +127,11 @@ class Request:
     tenant: str = ""
     cls: str = ""
     priority: int = 0
+    # scheduler mode: keep the (vocab,) float32 logits the first generated
+    # token was drawn from (one host copy per request, at its final
+    # prefill chunk) — for checking a served run against a reference
+    keep_first_logits: bool = False
+    first_logits: Optional[np.ndarray] = None
 
 
 _ENGINE_COUNTERS = (
@@ -508,8 +516,17 @@ class ServingEngine:
             # COW page copy) treats the store as an opaque pytree, so the
             # quantized layout rides through unchanged
             self.quant_kv = quant_kv
-            self._pages_kv = M.init_paged_caches(cfg, n_pages, sc.page_size,
-                                                 quantized=quant_kv)
+
+            def init_store():
+                return M.init_paged_caches(cfg, n_pages, sc.page_size,
+                                           quantized=quant_kv)
+            # built in place on the mesh (KV heads split over "model" where
+            # they divide): the store is the largest buffer the engine owns
+            store_specs = page_store_specs(jax.eval_shape(init_store),
+                                           cfg.n_kv_heads, mesh)
+            self._pages_kv = jax.jit(init_store, out_shardings={
+                k: NamedSharding(mesh, sp)
+                for k, sp in store_specs.items()})()
             # quantized pages hash/dedup by their int8 bytes: the prefix
             # keys carry a layout tag so a quantized page key can never
             # alias a bf16 one (tag 0 keeps legacy chains bit-identical)
@@ -532,14 +549,19 @@ class ServingEngine:
             self._c_quant_tok = self.metrics.counter("pool.quant_tokens")
             self._c_quant_hit = self.metrics.counter("pool.quant_hits")
             ms, lanes = sc.max_slots, sc.lanes
-            # device-resident batch state: touched only on control-plane
-            # events (admission / growth / eviction); the decode tick
-            # reads it in place with zero host traffic
-            self._page_tbl = jnp.full((ms, lanes), -1, jnp.int32)
-            self._clen = jnp.zeros((ms,), jnp.int32)
-            self._cur = jnp.zeros((ms, 1), jnp.int32)
+            # device-resident step inputs, replicated over the mesh the
+            # steps run on: touched only on control-plane events
+            # (admission / growth / eviction); the decode tick reads it in
+            # place with zero host traffic
+            self._replicated = NamedSharding(mesh, P())
+            self._page_tbl = self._put(np.full((ms, lanes), -1, np.int32))
+            self._clen = self._put(np.zeros((ms,), np.int32))
+            self._cur = self._put(np.zeros((ms, 1), np.int32))
+            self._active = self._put(np.zeros((ms,), np.int32))
+            # the batch's reader ids feed only the lease programs, which
+            # live with the one lease table on the default device (a
+            # Mosaic kernel cannot be partitioned over a mesh)
             self._rids = jnp.full((ms,), -1, jnp.int32)
-            self._active = jnp.zeros((ms,), jnp.int32)
             self._decode_paged = jit_step(
                 make_decode_step(cfg, mesh, rules, paged=True),
                 donate_argnums=(1,))
@@ -584,6 +606,10 @@ class ServingEngine:
                 self._g_slot_cap.set(sc.max_slots)
                 self._g_free_frac.set(sc.admit_free_frac)
                 self._ctrl_next_ns = 0
+
+    def _put(self, host_array: np.ndarray) -> jax.Array:
+        """Upload step input, replicated over the engine's mesh."""
+        return jax.device_put(host_array, self._replicated)
 
     # ------------------------------------------------------------- handlers
     def _handler(self, hid: int) -> None:
@@ -910,13 +936,13 @@ class ServingEngine:
             ptbl[i, :len(st.pages)] = st.pages
             rids[i] = st.rid
         rid_dev = jnp.asarray(rids)
-        args = map(jnp.asarray, (toks, clens, newls, ptbl))
+        args = map(self._put, (toks, clens, newls, ptbl))
         t0 = time.monotonic_ns()
         ptok, _ = self.pages.read_batch(rid_dev)
         try:
             rtok, params, _ = self.store.read_batch(rid_dev)
             try:
-                nxt, self._pages_kv = self._prefill_paged(
+                nxt, last_logits, self._pages_kv = self._prefill_paged(
                     params, self._pages_kv, *args)
             finally:
                 self.store.done_read_batch(rtok, rid_dev)
@@ -937,6 +963,10 @@ class ServingEngine:
                     self._publish_prefix(st)   # prompt pages fully written
                 tok = int(nxt_h[i])     # final chunk: first generated token
                 first_toks += 1
+                r = st.request
+                if r is not None and r.keep_first_logits \
+                        and r.first_logits is None:
+                    r.first_logits = np.asarray(last_logits[i], np.float32)
                 row = st.row
                 self._cur = self._cur.at[row, 0].set(tok)
                 self._clen = self._clen.at[row].set(st.pos + 1)
@@ -1071,6 +1101,34 @@ class ServingEngine:
                 if r is not None:
                     self._submit_slot(r)
 
+    def compile_steps(self) -> Dict[str, tuple]:
+        """Compile the scheduler's paged decode and prefill steps ahead of
+        time at the engine's fixed shapes, and serve with exactly these
+        executables from then on (call before :meth:`start`; the first
+        ticks then pay no compilation).  -> ``{step: (compile seconds,
+        compiled executable)}`` — the executable's ``as_text()`` is the
+        program every tick runs."""
+        if self.scheduler is None:
+            raise ValueError("compile_steps needs scheduler mode")
+        sc = self.sched_cfg
+        rows, width, lanes = sc.prefill_rows, sc.prefill_chunk, sc.lanes
+        prefill_args = (np.zeros((rows, width), np.int32),
+                        np.zeros((rows,), np.int32),
+                        np.zeros((rows,), np.int32),
+                        np.full((rows, lanes), -1, np.int32))
+        out = {}
+        for name, attr, args in (
+                ("decode", "_decode_paged",
+                 (self._cur, self._clen, self._page_tbl)),
+                ("prefill", "_prefill_paged",
+                 tuple(map(self._put, prefill_args)))):
+            t0 = time.perf_counter()
+            compiled = getattr(self, attr).lower(
+                self.store.params, self._pages_kv, *args).compile()
+            out[name] = (time.perf_counter() - t0, compiled)
+            setattr(self, attr, compiled)
+        return out
+
     # ------------------------------------------------------- background ops
     def _updater(self, period_s: float, perturb: Callable[[Any], Any]):
         while not self._stop.wait(period_s):
@@ -1078,14 +1136,18 @@ class ServingEngine:
 
     def _compactor(self, period_s: float):
         while not self._stop.wait(period_s):
-            if self.scheduler is not None:
-                # the scheduler thread is the only page allocator in this
-                # mode; hand it the request so the live-rid snapshot can
-                # never race an in-flight admission
-                self._compact_req = True
-            else:
-                self.pages.compact()
-                self.stats.inc("compactions")
+            self.request_compaction()
+
+    def request_compaction(self) -> None:
+        """One compaction tick: in scheduler mode the scheduler thread (the
+        only page allocator there) services it at its next tick, so the
+        live-rid snapshot can never race an in-flight admission; orphan
+        pages it finds cost their stripes one revocation + drain."""
+        if self.scheduler is not None:
+            self._compact_req = True
+        else:
+            self.pages.compact()
+            self.stats.inc("compactions")
 
     # ---------------------------------------------------- hot swap (PR 7)
     def stage_checkpoint(self, directory, step: int):

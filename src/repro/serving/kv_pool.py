@@ -299,43 +299,48 @@ def _insert_prefix_impl(owner, map_kh, map_kl, map_pg, map_ln, map_age,
     """Publish a request's freshly written prompt pages into the index:
     key ``i`` maps to the request's page ``lane_pg[i]``, which converts
     from private to shared-refcount-1 (the inserter's own ref — its reads
-    must outlive any later hit).  Way choice per key: a key already
-    present in its set is skipped (the older entry keeps serving hits);
-    otherwise the first VACANT way, or — set full — the way with the
-    OLDEST ``map_age`` stamp is evicted (entry only; the victim page's
-    owner/refcount state is untouched).  Among same-set candidates in one
-    batch the first wins, like the publish kernel's CAS ordering.
-    ``stamp`` is the pool's monotonic insert clock (traced scalar)."""
+    must outlive any later hit).  Keys insert one after another, in lane
+    order.  Way choice per key: a key already present in its set is
+    skipped (the older entry keeps serving hits); otherwise the first
+    VACANT way, or — set full — the way with the OLDEST ``map_age`` stamp
+    among entries of EARLIER inserts is evicted (entry only; the victim
+    page's owner/refcount state is untouched).  A key never evicts an
+    entry of its own batch: that would punch a hole into the chain it is
+    publishing, and a hole ends every later match.  A set full of this
+    batch's entries skips the key.  ``stamp`` is the pool's monotonic
+    insert clock (traced scalar)."""
     n_pages = owner.shape[0]
     map_slots = map_pg.shape[0]
     n_sets = map_slots // ways
     set_i = kl & (n_sets - 1)
-    m = kh.shape[0]
-    idx = jnp.arange(m)
     valid = (ln > 0) & (lane_pg >= 0) \
         & (owner[jnp.clip(lane_pg, 0)] == rid)
-    dup_earlier = (set_i[None, :] == set_i[:, None]) \
-        & (idx[None, :] < idx[:, None]) & valid[None, :]
-    first = ~jnp.any(dup_earlier, axis=1)
-    slots = set_i[:, None] * ways + jnp.arange(ways)[None, :]   # (m, ways)
-    occ = map_pg[slots] >= 0
-    key_eq = (map_kh[slots] == kh[:, None]) \
-        & (map_kl[slots] == kl[:, None]) & (map_ln[slots] == ln[:, None])
-    present = jnp.any(occ & key_eq, axis=1)
-    vac = ~occ
-    age_w = jnp.where(occ, map_age[slots], jnp.iinfo(jnp.int32).max)
-    way = jnp.where(jnp.any(vac, axis=1), jnp.argmax(vac, axis=1),
-                    jnp.argmin(age_w, axis=1))
-    ins = valid & first & ~present
-    tgt_slot = jnp.where(ins, set_i * ways + way, map_slots)
-    new_kh = map_kh.at[tgt_slot].set(kh, mode="drop")
-    new_kl = map_kl.at[tgt_slot].set(kl, mode="drop")
-    new_pg = map_pg.at[tgt_slot].set(lane_pg, mode="drop")
-    new_ln = map_ln.at[tgt_slot].set(ln, mode="drop")
-    new_age = map_age.at[tgt_slot].set(stamp, mode="drop")
-    tgt_pg = jnp.where(ins, lane_pg, n_pages)
-    new_owner = owner.at[tgt_pg].set(-2, mode="drop")   # refcount 1
-    return new_owner, new_kh, new_kl, new_pg, new_ln, new_age, ins
+
+    def body(i, carry):
+        own, mkh, mkl, mpg, mln, mage, ins = carry
+        slots = set_i[i] * ways + jnp.arange(ways)
+        occ = mpg[slots] >= 0
+        present = jnp.any(occ & (mkh[slots] == kh[i])
+                          & (mkl[slots] == kl[i]) & (mln[slots] == ln[i]))
+        vac = ~occ
+        older = occ & (mage[slots] < stamp)
+        age_w = jnp.where(older, mage[slots], jnp.iinfo(jnp.int32).max)
+        way = jnp.where(jnp.any(vac), jnp.argmax(vac), jnp.argmin(age_w))
+        ok = valid[i] & ~present & (jnp.any(vac) | jnp.any(older))
+        tgt = jnp.where(ok, slots[way], map_slots)
+        pg = jnp.where(ok, lane_pg[i], n_pages)
+        return (own.at[pg].set(-2, mode="drop"),      # refcount 1
+                mkh.at[tgt].set(kh[i], mode="drop"),
+                mkl.at[tgt].set(kl[i], mode="drop"),
+                mpg.at[tgt].set(lane_pg[i], mode="drop"),
+                mln.at[tgt].set(ln[i], mode="drop"),
+                mage.at[tgt].set(stamp, mode="drop"),
+                ins.at[i].set(ok))
+
+    return jax.lax.fori_loop(
+        0, kh.shape[0], body,
+        (owner, map_kh, map_kl, map_pg, map_ln, map_age,
+         jnp.zeros(kh.shape, bool)))
 
 
 def _release_refs_impl(owner, pages):

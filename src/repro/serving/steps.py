@@ -94,7 +94,7 @@ def make_decode_step(cfg: ModelConfig, mesh: Mesh, rules: MeshRules,
 
 def make_paged_prefill_step(cfg: ModelConfig, mesh: Mesh, rules: MeshRules):
     """prefill_chunk(params, caches, tokens, cache_len, chunk_lens, pages)
-    -> (next_token, caches').
+    -> (next_token, last_logits, caches').
 
     One continuous-batching prefill tick: ``tokens`` is a (R, C) batch of
     RIGHT-ALIGNED prompt chunks (row i's last ``chunk_lens[i]`` columns are
@@ -104,8 +104,9 @@ def make_paged_prefill_step(cfg: ModelConfig, mesh: Mesh, rules: MeshRules):
     attend causally to everything already paged — so a long prompt prefills
     over several ticks without re-running earlier chunks.  Because chunks
     are right-aligned, ``next_token`` (argmax at the last column) is the
-    request's first generated token whenever this was its final chunk;
-    rows mid-prompt (or padding rows, ``chunk_lens == 0``) return garbage
+    request's first generated token whenever this was its final chunk,
+    and ``last_logits`` (R, vocab) the logits it was drawn from; rows
+    mid-prompt (or padding rows, ``chunk_lens == 0``) return garbage
     there, which the scheduler ignores."""
 
     def prefill(params, caches, tokens, cache_len, chunk_lens, pages):
@@ -113,7 +114,8 @@ def make_paged_prefill_step(cfg: ModelConfig, mesh: Mesh, rules: MeshRules):
                                       mesh=mesh, rules=rules, caches=caches,
                                       cache_len=cache_len, pages=pages,
                                       new_lens=chunk_lens)
-        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        return nxt, caches
+        last = logits[:, -1]
+        nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
+        return nxt, last, caches
 
     return prefill

@@ -115,10 +115,11 @@ except ImportError:                                       # pragma: no cover
 
 class HostModel:
     """Pure-python mirror of the pool's owner encoding + set-associative
-    prefix index (min(4, map_slots)-way sets, oldest-entry eviction when a
-    set is full — same tie-breaking as the device program: lowest way
-    among vacants / among minimal ages); the sweep checks the device
-    state against it after every operation."""
+    prefix index (min(4, map_slots)-way sets, keys inserted in lane order,
+    oldest-entry eviction among EARLIER inserts' entries when a set is
+    full — same tie-breaking as the device program: lowest way among
+    vacants / among minimal ages); the sweep checks the device state
+    against it after every operation."""
 
     def __init__(self, n_pages, map_slots):
         self.owner = np.full(n_pages, FREE, np.int64)
@@ -182,23 +183,22 @@ class HostModel:
 
     def insert(self, rid, kh, kl, ln, lane_pg):
         self.clock += 1
+        valid = [ln[i] > 0 and lane_pg[i] >= 0
+                 and self.owner[lane_pg[i]] == rid for i in range(len(kh))]
         ins = []
-        seen_sets = set()
         for i in range(len(kh)):
             slots = self._set_slots(kl[i])
-            valid = (ln[i] > 0 and lane_pg[i] >= 0
-                     and self.owner[lane_pg[i]] == rid)
-            first = slots[0] not in seen_sets
-            if valid:
-                seen_sets.add(slots[0])
             present = any(
                 s in self.map and self.map[s][:3]
                 == (int(kh[i]), int(kl[i]), int(ln[i])) for s in slots)
-            ok = valid and first and not present
+            vac = [s for s in slots if s not in self.map]
+            # never evict an entry of this same insert (a chain hole)
+            older = [s for s in slots
+                     if s in self.map and self.age[s] < self.clock]
+            ok = valid[i] and not present and bool(vac or older)
             if ok:
-                vac = [s for s in slots if s not in self.map]
                 slot = vac[0] if vac else min(
-                    slots, key=lambda s: (self.age[s], s))
+                    older, key=lambda s: (self.age[s], s))
                 self.map[slot] = (int(kh[i]), int(kl[i]), int(ln[i]),
                                   int(lane_pg[i]))
                 self.age[slot] = self.clock
