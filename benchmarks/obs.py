@@ -10,6 +10,9 @@ smoke gates — exit nonzero on any lost guarantee):
   site is ONE branch (``if _TR.enabled:``): its cost must be noise
   (< 250 ns even under CPython attribute-lookup pessimism).  Enabled,
   one ring emit must stay under 10 µs.
+* ``tick_cost`` — the scheduler's per-tick instrumentation alone (the
+  clock reads, the nine spans of a decode tick and its clock anchor),
+  with no profiler session active and with one: gated < 100 µs a tick.
 * ``step_overhead`` — the same scheduler-engine decode workload run
   twice, tracing off then on.  The gated number is the per-step tracing
   cost (measured events/step x measured emit cost) as a fraction of the
@@ -22,8 +25,8 @@ smoke gates — exit nonzero on any lost guarantee):
   and survives a ``json`` round-trip, and every request must derive a
   complete lifecycle (admit -> first token -> done, TTFT defined).
 * ``zero_sync`` — tracing ENABLED, the registry acquire/release pair
-  still runs under ``jax.transfer_guard("disallow")``: the device-side
-  counters fold on device and are harvested only in ``stats()``.
+  still runs under ``jax.transfer_guard("disallow")``: tracing adds no
+  transfer to the lease fast path.
 
     PYTHONPATH=src python -m benchmarks.obs            # full, writes JSON
     PYTHONPATH=src python -m benchmarks.obs --smoke    # CI gate
@@ -33,7 +36,9 @@ from __future__ import annotations
 
 import argparse
 import json
+import shutil
 import sys
+import tempfile
 import time
 from pathlib import Path
 
@@ -47,7 +52,7 @@ from repro import configs
 from repro.core import registry as REG
 from repro.dist.sharding import MeshRules
 from repro.models import model as M
-from repro.obs import TRACER
+from repro.obs import TRACER, clock_anchor
 from repro.obs.chrome import to_chrome, validate
 from repro.obs.trace import Tracer, derive_requests
 from repro.serving.engine import EngineConfig, Request, ServingEngine
@@ -187,6 +192,44 @@ def _traced_run(prompts, want, max_new, traced: bool):
         TRACER.disable()
 
 
+TICK_SPANS = ("sched_tick", "tick_admit", "tick_grow", "decode_step",
+              "lease_acquire", "step_dispatch", "lease_release",
+              "step_readback", "tick_finish")
+
+
+def bench_tick_cost(n: int) -> dict:
+    """Per decode tick: the five clock reads the engine takes for its
+    phases, the tick's spans and one clock anchor, on a private tracer;
+    then the same inside a ``jax.profiler`` session (where the anchor is
+    recorded)."""
+    tr = Tracer(capacity=1 << 16)
+    tr.enable()
+
+    def per_tick_us() -> float:
+        t0 = time.perf_counter_ns()
+        for i in range(n):
+            marks = [time.monotonic_ns() for _ in range(5)]
+            for name in TICK_SPANS:
+                tr.emit_span("engine", name, marks[0], dur_ns=1, tick=i)
+            clock_anchor()
+        return (time.perf_counter_ns() - t0) / n / 1e3
+
+    off = per_tick_us()
+    d = tempfile.mkdtemp(prefix="obs-tick-")
+    jax.profiler.start_trace(d)
+    try:
+        on = per_tick_us()
+    finally:
+        jax.profiler.stop_trace()
+        shutil.rmtree(d, ignore_errors=True)
+    check(off < 100 and on < 100,
+          f"tick instrumentation {off:.1f} µs, {on:.1f} µs profiled "
+          f"(< 100 µs a tick)")
+    return {"ticks": n, "spans_per_tick": len(TICK_SPANS),
+            "per_tick_us": round(off, 2),
+            "per_tick_profiled_us": round(on, 2)}
+
+
 def bench_step_overhead(max_new: int, n_req: int, emit_ns: float) -> dict:
     prompts = [np.arange(1, 8, dtype=np.int32) + i for i in range(n_req)]
     want = [_dense_reference(p, max_new) for p in prompts]
@@ -284,11 +327,7 @@ def bench_zero_sync(batch: int = 16) -> dict:
         TRACER.disable()
     check(guard_ok, "traced registry pair runs under "
                     "jax.transfer_guard('disallow')")
-    check(st["denied_publishes"] == 0,
-          f"device-side denied counter harvested clean "
-          f"(got {st['denied_publishes']})")
     return {"traced_guard_disallow_ok": guard_ok,
-            "denied_publishes": st["denied_publishes"],
             "publishes": st["publishes"]}
 
 
@@ -304,6 +343,7 @@ def main() -> int:
         "jax": jax.__version__,
         "model": CFG.name,
         "emit_cost": emit_rec,
+        "tick_cost": bench_tick_cost(n=5_000 if smoke else 20_000),
         "step_overhead": bench_step_overhead(
             max_new, n_req, emit_rec["enabled_emit_ns"]),
         "chrome_hotswap": bench_chrome_hotswap(max_new, n_req),

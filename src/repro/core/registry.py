@@ -153,13 +153,6 @@ def _scrub_impl(table, val):
     return jnp.where(table == val, 0, table)
 
 
-def _fold_denied_impl(acc, granted):
-    """Fold the batch's denied-publish count into a device scalar: the
-    slow-path pressure counter stays device-resident (dispatch-only add,
-    no transfer) and is harvested only by the synchronizing ``stats()``."""
-    return acc + granted.size - jnp.sum(granted.astype(jnp.int32))
-
-
 class _Programs(NamedTuple):
     acquire: object
     acquire_by_index: object
@@ -168,7 +161,6 @@ class _Programs(NamedTuple):
     release_by_index: object
     scatter: object
     scrub: object
-    fold_denied: object
 
 
 @functools.lru_cache(maxsize=None)
@@ -183,8 +175,7 @@ def _programs() -> _Programs:
         release_all=K.jit_donating(_release_ids32_all_impl, 1),
         release_by_index=K.jit_donating(_release_by_index_impl, 1),
         scatter=K.jit_donating(_scatter_impl, 1),
-        scrub=K.jit_donating(_scrub_impl, 1),
-        fold_denied=K.jit_donating(_fold_denied_impl, 1))
+        scrub=K.jit_donating(_scrub_impl, 1))
 
 
 # ---------------------------------------------------------------------------
@@ -253,9 +244,6 @@ class BravoRegistry:
         self._c_lane_scrubs = self.metrics.counter("registry.lane_scrubs")
         self._h_revocation = self.metrics.histogram("registry.revocation_ns")
         self._h_drain_wait = self.metrics.histogram("registry.drain_wait_ns")
-        # device-resident slow-path pressure counter: denied publishes are
-        # folded in-graph (dispatch-only) and harvested only in stats()
-        self._dev_denied = jnp.zeros((), jnp.int32)
 
     # counter attribute compatibility (reads only; writes go through the
     # metrics registry so per-thread cells keep increments lock-free)
@@ -433,8 +421,6 @@ class BravoRegistry:
             if _TR.enabled:
                 _TR.emit("lock", "publish", lock=h.name,
                          batch=int(reader_ids.size))
-                self._dev_denied = _programs().fold_denied(
-                    self._dev_denied, granted)
         return granted
 
     def release(self, h: "RegistryHandle", reader_ids: jax.Array,
@@ -463,8 +449,6 @@ class BravoRegistry:
             if _TR.enabled:
                 _TR.emit("lock", "publish", lock="by_index",
                          batch=int(reader_ids.size))
-                self._dev_denied = _programs().fold_denied(
-                    self._dev_denied, granted)
         return granted
 
     def release_by_index(self, lock_idx: jax.Array, reader_ids: jax.Array,
@@ -640,10 +624,7 @@ class BravoRegistry:
                     "drain_timeouts": self.drain_timeouts,
                     "lane_scrubs": self.lane_scrubs,
                     "armed": int(self._armed.sum()),
-                    "rbias_armed": int(jnp.sum(self.rbias)),
-                    # harvest of the device-resident fold (only while
-                    # tracing was enabled; zero otherwise)
-                    "denied_publishes": int(self._dev_denied)}
+                    "rbias_armed": int(jnp.sum(self.rbias))}
 
 
 # ---------------------------------------------------------------------------

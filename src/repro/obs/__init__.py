@@ -19,6 +19,9 @@ Three pieces, one contract:
   emit site; device-side counters are folded as dispatch-only adds and
   harvested only at control-event boundaries.  ``benchmarks/obs.py``
   measures both and gates them in CI.
+* the **clock** — :func:`~.trace.clock_anchor` marks the spans' clock
+  (``time.monotonic_ns``) on the JAX profiler's timeline, so a device
+  profile places the spans beside the device's ops.
 
 The process-wide tracer lives here (``TRACER``): events from every
 subsystem merge into one timeline, which is what makes a chaos failure
@@ -33,13 +36,13 @@ from .chrome import to_chrome, validate as validate_chrome  # noqa: F401
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,  # noqa: F401
                       default_metrics)
 from .slo import SLOReport, SLOTarget, WindowedHistogram  # noqa: F401
-from .trace import (CATEGORIES, TraceEvent, Tracer,  # noqa: F401
-                    derive_requests, format_timeline)
+from .trace import (CATEGORIES, CLOCK_ANCHOR, TraceEvent,  # noqa: F401
+                    Tracer, clock_anchor, derive_requests, format_timeline)
 
 __all__ = ["TRACER", "tracer", "enable", "disable", "clear", "snapshot",
            "Tracer", "TraceEvent", "derive_requests", "format_timeline",
-           "CATEGORIES", "Counter", "Gauge", "Histogram",
-           "MetricsRegistry", "default_metrics", "to_chrome",
+           "clock_anchor", "CLOCK_ANCHOR", "CATEGORIES", "Counter", "Gauge",
+           "Histogram", "MetricsRegistry", "default_metrics", "to_chrome",
            "chrome_dumps", "validate_chrome", "COUNTER_EVENTS",
            "WindowedHistogram", "SLOTarget", "SLOReport"]
 

@@ -175,7 +175,7 @@ class SLOTarget:
     disabled (not asserted, not counted against attainment)."""
 
     name: str = "default"
-    ttft_ms: float = 0.0       # admission -> first generated token
+    ttft_ms: float = 0.0       # submission -> first generated token
     tpot_ms: float = 0.0       # mean per-token decode latency
     step_ms: float = 0.0       # engine step-latency target (controller
     #                            sensor, not a per-request clause)

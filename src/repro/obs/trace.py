@@ -43,10 +43,16 @@ category     events
 ``pool``     KV-page lifetime: ``alloc``, ``reclaim``, ``dedup_hit`` /
              ``dedup_miss``, ``cow_copy``, ``ref_release``,
              ``prefix_insert``, ``orphan_scrub``
-``engine``   serving mechanisms: ``step_decode`` / ``step_prefill``
-             (spans), ``swap_stage``, ``swap_begin``, ``swap_land``,
-             ``swap_degrade``, ``swap_abandon``, ``worker_crash``,
-             ``compact``
+``engine``   serving mechanisms: ``swap_stage``, ``swap_begin``,
+             ``swap_land`` (span), ``swap_degrade``, ``swap_abandon``,
+             ``worker_crash``, ``compact``; and the scheduler thread's
+             spans, each with ``tick`` (the loop iteration that caused
+             it): ``sched_tick`` (one step tick, whole), inside it the
+             phases ``tick_compact``, ``tick_admit``, ``tick_grow``,
+             ``lease_acquire``, ``step_dispatch``, ``lease_release``,
+             ``step_readback``, ``tick_finish``, and ``decode_step`` /
+             ``prefill_step`` (lease publish to token read-back);
+             ``sched_idle`` covers a run of idle iterations
 ``sched``    pure-policy decisions: ``admit``, ``evict``, ``finish``,
              ``defer``, and the latency-feedback controller's
              ``ctrl_shrink`` / ``ctrl_grow`` (admission watermark and
@@ -55,6 +61,12 @@ category     events
 ``fault``    injected faults (``repro.ft.faults``): ``inject`` with the
              fault name — every chaos failure carries its timeline
 ===========  ==============================================================
+
+Clock: every timestamp is ``time.monotonic_ns``.  :func:`clock_anchor`
+puts that clock into a device profile (the JAX profiler's own clock), so
+a profile taken while the engine runs can place these spans beside the
+device's ops: the scheduler emits one anchor per step tick and one per
+``sched_idle``.
 """
 
 from __future__ import annotations
@@ -64,9 +76,25 @@ import time
 from typing import Any, Dict, List, NamedTuple, Optional
 
 __all__ = ["Tracer", "TraceEvent", "format_timeline", "derive_requests",
-           "CATEGORIES"]
+           "clock_anchor", "CATEGORIES", "CLOCK_ANCHOR"]
 
 CATEGORIES = ("req", "lock", "pool", "engine", "sched", "fault")
+CLOCK_ANCHOR = "obs.clock"      # profiler annotation name; stat ``mono_ns``
+
+
+def clock_anchor() -> int:
+    """Mark ``time.monotonic_ns()`` on the JAX profiler's timeline: a
+    zero-length ``obs.clock`` annotation whose ``mono_ns`` stat is the
+    reading taken just before it.  The annotation's start on the
+    profile's clock minus ``mono_ns`` is that clock's offset plus the
+    entry latency, so the smallest such difference over a profile's
+    anchors is the offset.  With no profiler session active the
+    annotation is one check in C++.  Returns the reading."""
+    from jax.profiler import TraceAnnotation   # obs itself needs no jax
+    now = time.monotonic_ns()
+    with TraceAnnotation(CLOCK_ANCHOR, mono_ns=now):
+        pass
+    return now
 
 
 class TraceEvent(NamedTuple):
@@ -233,8 +261,10 @@ def derive_requests(events: List[TraceEvent]) -> Dict[int, Dict[str, Any]]:
     Returns ``{rid: {...}}`` with the admit/first-token/done timestamps
     plus the derived latencies the SLO work needs as sensors:
 
-    * ``ttft_ns``  — first generated token minus admission (time to
-      first token; None until both ends exist);
+    * ``ttft_ns``  — first generated token minus submission (time to
+      first token, queueing included; None until both ends exist);
+    * ``queue_ns`` — first admission minus submission (the part of
+      TTFT spent waiting for a slot and pages);
     * ``tpot_ns``  — (done - first token) / (tokens - 1), the mean
       time per output token across the decode phase;
     * ``evictions`` / ``preemptions`` / ``prefill_chunks`` /
@@ -245,8 +275,8 @@ def derive_requests(events: List[TraceEvent]) -> Dict[int, Dict[str, Any]]:
     Preemption safety: a LIFO-preempted request re-prefills after
     requeue and emits ``admit`` / ``first_token`` again — both are
     derived from the FIRST occurrence only, so TTFT always measures
-    the original admission to the original first token, never the
-    (shorter) re-prefill of an already-generated prefix.
+    submission to the original first token, never the re-prefill of an
+    already-generated prefix.
     """
     reqs: Dict[int, Dict[str, Any]] = {}
 
@@ -255,7 +285,7 @@ def derive_requests(events: List[TraceEvent]) -> Dict[int, Dict[str, Any]]:
             "submit_ts": None, "admit_ts": None, "first_token_ts": None,
             "done_ts": None, "tokens": 0, "evictions": 0, "preemptions": 0,
             "prefill_chunks": 0, "cached_tokens": 0,
-            "ttft_ns": None, "tpot_ns": None})
+            "ttft_ns": None, "queue_ns": None, "tpot_ns": None})
 
     for e in events:
         if e.cat != "req" or not e.args or "rid" not in e.args:
@@ -279,8 +309,11 @@ def derive_requests(events: List[TraceEvent]) -> Dict[int, Dict[str, Any]]:
             r["evictions"] += 1
             r["preemptions"] += 1
     for r in reqs.values():
-        if r["admit_ts"] is not None and r["first_token_ts"] is not None:
-            r["ttft_ns"] = r["first_token_ts"] - r["admit_ts"]
+        if r["submit_ts"] is not None:
+            if r["first_token_ts"] is not None:
+                r["ttft_ns"] = r["first_token_ts"] - r["submit_ts"]
+            if r["admit_ts"] is not None:
+                r["queue_ns"] = r["admit_ts"] - r["submit_ts"]
         if (r["first_token_ts"] is not None and r["done_ts"] is not None
                 and r["tokens"] > 1):
             r["tpot_ns"] = (r["done_ts"] - r["first_token_ts"]) \

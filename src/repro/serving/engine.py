@@ -72,6 +72,7 @@ from ..models import model as M
 from ..models.common import ModelConfig
 from ..kernels.quant import quant_layout_tag
 from ..obs import TRACER as _TR
+from ..obs import clock_anchor
 from ..obs.metrics import MetricsRegistry
 from .kv_pool import KVPool, page_keys
 from .scheduler import (LatencyFeedbackController, Phase, Scheduler,
@@ -132,6 +133,7 @@ class Request:
     # prefill chunk) — for checking a served run against a reference
     keep_first_logits: bool = False
     first_logits: Optional[np.ndarray] = None
+    submit_ns: int = 0                 # monotonic_ns at submit(), TTFT's 0
 
 
 _ENGINE_COUNTERS = (
@@ -577,6 +579,9 @@ class ServingEngine:
                 donate_argnums=(0,))
             self._free_est = n_pages        # host mirror of pool pressure
             self._compact_req = False
+            # scheduler loop iterations so far: every span the loop emits
+            # carries the one that caused it as ``tick``
+            self._tick = 0
             # decode steps seen so far: the first obs_warmup_steps stay
             # out of the latency histogram (compile-time outliers would
             # dominate p99 for the whole run)
@@ -698,7 +703,7 @@ class ServingEngine:
         self.scheduler.submit(SlotState(
             rid=r.rid, prefix=np.asarray(r.prompt, np.int32),
             max_new=r.max_new, request=r, tenant=r.tenant, cls=r.cls,
-            priority=r.priority))
+            priority=r.priority, submit_ns=r.submit_ns))
 
     def _drain_inq(self) -> None:
         while True:
@@ -861,8 +866,6 @@ class ServingEngine:
         st.shared_refs = refs
         st.cached_pos = cov
         st.prefill_pos = st.pos = cov     # chunked prefill resumes here
-        st.admit_ns = time.monotonic_ns()  # TTFT sensor anchor (latest
-        #                                    admission; trace keeps first)
         self._rids = self._rids.at[st.row].set(st.rid)
         self._bind_pages(st, refs + pages, charged=len(pages) + revived)
         self.stats.inc("pages_charged", len(pages))
@@ -942,19 +945,25 @@ class ServingEngine:
         try:
             rtok, params, _ = self.store.read_batch(rid_dev)
             try:
+                t1 = time.monotonic_ns()
                 nxt, last_logits, self._pages_kv = self._prefill_paged(
-                    params, self._pages_kv, *args)
+                    params, self._pages_kv, *args)   # uploads ``args``
+                t2 = time.monotonic_ns()
             finally:
                 self.store.done_read_batch(rtok, rid_dev)
         finally:
             self.pages.done_read_batch(ptok)
+        t3 = time.monotonic_ns()
         nxt_h = np.asarray(nxt)
+        t4 = time.monotonic_ns()
         if _TR.enabled:
-            _TR.emit_span("engine", "prefill_step", t0,
-                          rows=len(plan.slots))
+            tick = self._tick
+            _TR.emit_span("engine", "prefill_step", t0, dur_ns=t4 - t0,
+                          rows=len(plan.slots), tick=tick)
+            self._emit_step_phases(t0, t1, t2, t3, t4)
             for st, chunk in zip(plan.slots, plan.chunks):
                 _TR.emit("req", "prefill_chunk", rid=st.rid, chunk=chunk,
-                         pos=st.prefill_pos)
+                         pos=st.prefill_pos, tick=tick)
         done: List[SlotState] = []
         first_toks = 0
         for i, (st, chunk) in enumerate(zip(plan.slots, plan.chunks)):
@@ -971,8 +980,11 @@ class ServingEngine:
                 self._cur = self._cur.at[row, 0].set(tok)
                 self._clen = self._clen.at[row].set(st.pos + 1)
                 self._active = self._active.at[row].set(1)
-                if st.admit_ns:
-                    ttft = time.monotonic_ns() - st.admit_ns
+                if st.submit_ns:
+                    # once, at the first token: a re-prefill after an
+                    # eviction makes no new first token
+                    ttft = time.monotonic_ns() - st.submit_ns
+                    st.submit_ns = 0
                     self._h_ttft.observe(ttft)
                     if self._w_ttft is not None:
                         self._w_ttft.observe(ttft)
@@ -987,37 +999,47 @@ class ServingEngine:
         self.stats.inc("tokens_out", first_toks)
         if self.quant_kv:
             self._c_quant_tok.add(int(np.sum(newls)))
+        if _TR.enabled:
+            _TR.emit_span("engine", "tick_finish", t4, tick=self._tick)
 
     def _run_decode(self, plan) -> None:
         """One decode tick over every DECODE row: grow pages first (with
         page-pressure eviction), then ONE fused lease batch per lock held
         across the step, one jitted step, zero host traffic on the lease
         fast path (only the generated tokens come back)."""
+        tg = time.monotonic_ns()
         for st in plan.grow:
             if st.phase is not Phase.DECODE:
                 continue                 # evicted by an earlier growth
             if not self._grow_slot(st, 1):
                 self._evict(st)          # no other victim: requeue itself
         slots = [st for st in plan.slots if st.phase is Phase.DECODE]
+        t0 = time.monotonic_ns()
+        if _TR.enabled:
+            _TR.emit_span("engine", "tick_grow", tg, dur_ns=t0 - tg,
+                          tick=self._tick)
         if not slots:
             return
-        t0 = time.monotonic_ns()
         rid_dev = self._rids
         ptok, _ = self.pages.read_batch(rid_dev)
         try:
             rtok, params, _ = self.store.read_batch(rid_dev)
             try:
+                t1 = time.monotonic_ns()
                 nxt, _logits, self._pages_kv = self._decode_paged(
                     params, self._pages_kv, self._cur, self._clen,
                     self._page_tbl)
+                self._clen = self._bump(self._clen, self._active)
+                t2 = time.monotonic_ns()
             finally:
                 self.store.done_read_batch(rtok, rid_dev)
         finally:
             self.pages.done_read_batch(ptok)
+        t3 = time.monotonic_ns()
         self._cur = nxt
-        self._clen = self._bump(self._clen, self._active)
         toks = np.asarray(nxt)[:, 0]     # the data-plane output sync
-        dt = time.monotonic_ns() - t0
+        t4 = time.monotonic_ns()
+        dt = t4 - t0
         self._steps_seen += 1
         if self._steps_seen > self.ecfg.obs_warmup_steps:
             self._h_step.observe(dt)
@@ -1025,7 +1047,8 @@ class ServingEngine:
                 self._w_step.observe(dt)
         if _TR.enabled:
             _TR.emit_span("engine", "decode_step", t0, dur_ns=dt,
-                          batch=len(slots))
+                          batch=len(slots), tick=self._tick)
+            self._emit_step_phases(t0, t1, t2, t3, t4)
         done = [st for st in slots
                 if self.scheduler.on_token(st, int(toks[st.row]))]
         for st in done:
@@ -1035,6 +1058,19 @@ class ServingEngine:
         self.stats.inc("tokens_out", len(slots))
         if self.quant_kv:
             self._c_quant_tok.add(len(slots))
+        if _TR.enabled:
+            _TR.emit_span("engine", "tick_finish", t4, tick=self._tick)
+
+    def _emit_step_phases(self, t0: int, t1: int, t2: int, t3: int,
+                          t4: int) -> None:
+        """The four phases of a step span [t0, t4): both lease publishes
+        (host read locks included), the step program's dispatch, both
+        lease releases, and the wait for the tokens."""
+        for name, a, b in (("lease_acquire", t0, t1),
+                           ("step_dispatch", t1, t2),
+                           ("lease_release", t2, t3),
+                           ("step_readback", t3, t4)):
+            _TR.emit_span("engine", name, a, dur_ns=b - a, tick=self._tick)
 
     def _ctrl_tick(self) -> None:
         """Latency-feedback admission update (paced to the controller's
@@ -1069,37 +1105,71 @@ class ServingEngine:
 
     def _schedule_tick(self) -> bool:
         """One policy round: service compaction, admit, run the plan.
-        Returns False when idle (the loop then blocks on the queue)."""
-        self._drain_inq()
-        self._g_queue.set(len(self.scheduler.waiting))
-        if self._compact_req:
+        Returns False when idle (the loop then blocks on the queue).  A
+        step tick's phases are spans (``tick_compact``, ``tick_admit``,
+        then those of the step it runs); an idle round emits none."""
+        t0 = time.monotonic_ns()
+        compacted = self._compact_req
+        if compacted:
             self._compact_req = False
             live = [s.rid for s in self.scheduler.running.values()]
             self._free_est += self.pages.compact(live=live)
             self.stats.inc("compactions")
             if _TR.enabled:
                 _TR.emit("engine", "compact")
+        t1 = time.monotonic_ns()
+        self._drain_inq()
+        self._g_queue.set(len(self.scheduler.waiting))
         if self._controller is not None:
             self._ctrl_tick()
         self._admit()
         plan = self.scheduler.plan()
+        if plan.kind not in ("prefill", "decode"):
+            return False
+        if _TR.enabled:
+            clock_anchor()
+            if compacted:
+                _TR.emit_span("engine", "tick_compact", t0, dur_ns=t1 - t0,
+                              tick=self._tick)
+            _TR.emit_span("engine", "tick_admit", t1, tick=self._tick)
         if plan.kind == "prefill":
             self._run_prefill(plan)
-            return True
-        if plan.kind == "decode":
+        else:
             self._run_decode(plan)
-            return True
-        return False
+        return True
 
     def _schedule_loop(self) -> None:
+        """Run ticks until stopped.  Every iteration is one ``tick``; a
+        step tick is one ``sched_tick`` span, and a run of idle
+        iterations (each polls the queue for ``idle_poll_s``) one
+        ``sched_idle`` span, emitted when the run ends."""
+        idle_t0 = idle_tick = 0           # start of the current idle run
         while not self._stop.is_set():
-            if not self._schedule_tick():
-                try:
-                    r = self.inq.get(timeout=self.ecfg.idle_poll_s)
-                except queue.Empty:
-                    continue
-                if r is not None:
-                    self._submit_slot(r)
+            self._tick += 1
+            t0 = time.monotonic_ns()
+            if self._schedule_tick():
+                if _TR.enabled:
+                    if idle_t0:
+                        _TR.emit_span("engine", "sched_idle", idle_t0,
+                                      dur_ns=t0 - idle_t0, tick=idle_tick,
+                                      polls=self._tick - idle_tick)
+                    _TR.emit_span("engine", "sched_tick", t0,
+                                  tick=self._tick)
+                idle_t0 = 0
+                continue
+            if not idle_t0:
+                idle_t0, idle_tick = t0, self._tick
+                if _TR.enabled:
+                    clock_anchor()
+            try:
+                r = self.inq.get(timeout=self.ecfg.idle_poll_s)
+            except queue.Empty:
+                continue
+            if r is not None:
+                self._submit_slot(r)
+        if idle_t0 and _TR.enabled:
+            _TR.emit_span("engine", "sched_idle", idle_t0, tick=idle_tick,
+                          polls=self._tick - idle_tick + 1)
 
     def compile_steps(self) -> Dict[str, tuple]:
         """Compile the scheduler's paged decode and prefill steps ahead of
@@ -1261,6 +1331,7 @@ class ServingEngine:
                 f"request {req.rid}: prompt {len(req.prompt)} + max_new "
                 f"{req.max_new} exceeds scheduler max_seq "
                 f"{self.sched_cfg.max_seq}")
+        req.submit_ns = time.monotonic_ns()
         if _TR.enabled:
             _TR.emit("req", "submit", rid=req.rid,
                      prompt=len(req.prompt), max_new=req.max_new)

@@ -87,10 +87,9 @@ class SlotState:
     cls: str = ""
     priority: int = 0                   # admission priority (higher first)
     request: Any = None                 # engine Request (opaque here)
-    admit_ns: int = 0                   # engine-owned: monotonic_ns of the
-    #                                     LATEST admission (TTFT sensor —
-    #                                     reporting TTFT comes from the
-    #                                     trace's FIRST admit instead)
+    submit_ns: int = 0                  # engine-owned: monotonic_ns of the
+    #                                     submission; the TTFT sensor reads
+    #                                     it at the first token, then 0
     # ---- prefix-cache state (engine-owned; policy only reads cached_pos)
     keys: Any = None                    # chained page keys (kh, kl, lens)
     cache_plan: Any = None              # (pool version, cov, k_ref, cow,

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -343,8 +344,29 @@ def test_derive_requests_preemption_keeps_first_admission():
     assert r["preemptions"] == 1 and r["evictions"] == 1
     evs = tr.snapshot()
     first_admit = next(e for e in evs if e.name == "admit")
+    submit = next(e for e in evs if e.name == "submit")
     assert r["admit_ts"] == first_admit.ts_ns    # FIRST admit, not requeue
-    assert r["ttft_ns"] == r["first_token_ts"] - first_admit.ts_ns
+    assert r["ttft_ns"] == r["first_token_ts"] - submit.ts_ns
+    assert r["queue_ns"] == first_admit.ts_ns - submit.ts_ns
+
+
+def test_derive_requests_ttft_counts_the_queue_wait():
+    tr = Tracer(capacity=256)
+    tr.enable()
+    tr.emit("req", "submit", rid=7)
+    time.sleep(0.02)                             # waits for a slot
+    tr.emit("req", "admit", rid=7)
+    tr.emit("req", "first_token", rid=7)
+    tr.emit("req", "done", rid=7, tokens=2)
+    tr.emit("req", "admit", rid=8)               # never submitted: no TTFT
+    tr.emit("req", "first_token", rid=8)
+    reqs = derive_requests(tr.snapshot())
+    r = reqs[7]
+    assert r["queue_ns"] >= 20_000_000
+    assert r["ttft_ns"] == r["first_token_ts"] - r["submit_ts"]
+    assert r["ttft_ns"] == r["queue_ns"] + (r["first_token_ts"]
+                                            - r["admit_ts"])
+    assert reqs[8]["ttft_ns"] is None and reqs[8]["queue_ns"] is None
 
 
 def test_slo_report_attainment_fold():
