@@ -20,8 +20,17 @@ Layout and grid
 * ``page_idx`` and ``cache_len`` ride in as **scalar-prefetch** operands
   (``PrefetchScalarGridSpec``): the index map reads ``page_idx[b, p]`` to
   pick which page tile the next grid step DMAs — the gather happens in the
-  block-fetch pipeline, not as a materialized ``take``.  Unused lanes
-  (``page_idx < 0``) clamp to page 0 and are masked out of the softmax.
+  block-fetch pipeline, not as a materialized ``take``.
+* the walk is bounded by each row's live length: a row of ``cache_len``
+  tokens holds ``n_live = ceil(cache_len / page_size)`` lanes.  Every lane
+  past ``n_live`` names the block of the row's last live lane (lane 0 for
+  an empty row), so consecutive dead steps name one block and the
+  pipeline issues no DMA for them, and the kernel body skips their
+  einsums, exps and accumulator updates.  A skipped lane would have added
+  nothing (``pexp`` 0, ``corr`` 1), so the output is unchanged, and a row
+  with ``cache_len == 0`` still emits zeros.  Inside the live lanes, slots
+  past ``cache_len`` and ``-1`` lanes (clamped to page 0) stay masked out
+  of the softmax.
 
 The pure-jnp oracle (:func:`~repro.kernels.ref.paged_attn_ref`) mirrors the
 page-walk order op for op so the CI smoke gate can require bit equality in
@@ -39,12 +48,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def live_lanes(cache_len, page_size: int):
+    """Lanes a row of ``cache_len`` tokens holds: ``ceil(cache_len /
+    page_size)``; the walk does work on these lanes only."""
+    return (cache_len + page_size - 1) // page_size
+
+
 def _make_paged_attn_kernel(lanes_per_step: int, quantized: bool):
     """Kernel factory.  ``lanes_per_step`` (autotune knob): how many page
     lanes each grid step consumes — every lane is its own scalar-prefetched
     (1, ps, KVH, hd) block, so a step with k lanes has k independent DMAs
-    in flight instead of one per step.  ``quantized``: the page blocks are
-    int8 and each is followed by its float32 per-page scale block
+    in flight instead of one per step.  Each lane runs only if it is live
+    (``p < n_live``), so a step may run some of its lanes and skip the
+    rest when ``n_live`` is not a multiple of k.  ``quantized``: the page
+    blocks are int8 and each is followed by its float32 per-page scale block
     (fetched through the SAME page-index map); dequantization is one cast
     + broadcast multiply at DMA time, inside VMEM — no fp32 copy of any
     page ever exists outside the kernel.  (The scales ride as (1, KVH, 1)
@@ -73,14 +90,13 @@ def _make_paged_attn_kernel(lanes_per_step: int, quantized: bool):
         q = q_ref[0].astype(jnp.float32)                  # (H, hd)
         qh = q.reshape(kvh, g, hd)                        # heads grouped by
         clen = cl_ref[b]                                  # their kv head
+        n_live = live_lanes(clen, ps)
 
-        for j in range(lanes_per_step):
-            lane = kv_refs[per_lane * j:per_lane * (j + 1)]
-            p = step * lanes_per_step + j
+        def attend(lane, p):
             page = pi_ref[b, p]
-            # positions this page covers; invalid lanes (past the request's
-            # length, or an unallocated/padding -1 page clamped to 0 by the
-            # index map) are masked
+            # positions this page covers; invalid slots (past the request's
+            # length, or an unallocated -1 page clamped to 0 by the index
+            # map) are masked
             pos = p * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
             valid = (pos < clen) & (page >= 0)            # (1, ps)
 
@@ -110,6 +126,11 @@ def _make_paged_attn_kernel(lanes_per_step: int, quantized: bool):
             acc_ref[...] = acc_ref[...] * corr + pv.reshape(h, hd)
             m_ref[...] = m_new
 
+        for j in range(lanes_per_step):
+            p = step * lanes_per_step + j
+            pl.when(p < n_live)(functools.partial(
+                attend, kv_refs[per_lane * j:per_lane * (j + 1)], p))
+
         @pl.when(step == n_steps - 1)
         def _emit():
             l = jnp.maximum(l_ref[...], 1e-20)            # fully-masked rows
@@ -137,15 +158,16 @@ def _paged_attn_common(q, kv_operands, page_idx, cache_len, interpret,
         kv_operands = kv_operands[:2] + tuple(
             x[:, :, None] for x in kv_operands[2:])
 
+    def page(j, bi, pi, idx_ref, cl_ref):
+        # a dead lane names the row's last live lane's page: no new DMA
+        last = jnp.maximum(live_lanes(cl_ref[bi], ps) - 1, 0)
+        return jnp.maximum(idx_ref[bi, jnp.minimum(pi * lps + j, last)], 0)
+
     def kv_map(j):
-        def m(bi, pi, idx_ref, cl_ref):
-            return (jnp.maximum(idx_ref[bi, pi * lps + j], 0), 0, 0, 0)
-        return m
+        return lambda *a: (page(j, *a), 0, 0, 0)
 
     def scale_map(j):
-        def m(bi, pi, idx_ref, cl_ref):
-            return (jnp.maximum(idx_ref[bi, pi * lps + j], 0), 0, 0)
-        return m
+        return lambda *a: (page(j, *a), 0, 0)
 
     in_specs = [pl.BlockSpec((1, h, hd), lambda bi, pi, idx, cl: (bi, 0, 0))]
     operands = []

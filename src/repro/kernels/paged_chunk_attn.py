@@ -27,8 +27,18 @@ Layout and grid
   operands (``PrefetchScalarGridSpec``): the index map reads
   ``page_idx[b, p]`` to pick which page tile the next grid step DMAs — the
   gather happens in the block-fetch pipeline, never as a materialized
-  ``take``.  Unused lanes (``page_idx < 0``) clamp to page 0 and are
-  masked out of the softmax.
+  ``take``.
+* the walk is bounded per (row, q-block): the block needs the lanes up to
+  the smaller of the row's last page, ``ceil(cache_len / page_size)``, and
+  its causal end, ``q_pos_max // page_size + 1`` with ``q_pos_max`` the
+  position of its last column; a block of padding columns only (every
+  block of a ``new_lens == 0`` row) needs none.  Every lane past that
+  names the block of the last needed lane (lane 0 where none is needed),
+  so consecutive dead steps name one block and the pipeline issues no
+  DMA for them, and the kernel body skips their einsums, exps and
+  accumulator updates.  A skipped lane would have added nothing, so the
+  output is unchanged; inside the needed lanes, ``-1`` lanes clamp to
+  page 0 and stay masked out of the softmax.
 
 Masking (all inside the kernel, per (q position, kv position) pair):
 * kv position ``t`` is valid iff ``t < cache_len[b]`` and its lane holds a
@@ -53,6 +63,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .paged_attn import live_lanes
+
 
 def _pick_block_q(s: int, limit: int = 32) -> int:
     """The q-block height: the largest divisor of ``s`` that is <= ``limit``
@@ -69,6 +81,16 @@ def _pick_block_q(s: int, limit: int = 32) -> int:
         if s % bq == 0:
             return bq
     raise AssertionError(s)          # unreachable: 1 divides everything
+
+
+def _needed_lanes(clen, nl, qi, s: int, bq: int, ps: int):
+    """Lanes q-block ``qi`` of a row attends to: up to the smaller of the
+    row's last page and the block's causal end; none for a block made of
+    padding columns only."""
+    q_pos_max = clen - s + (qi + 1) * bq - 1
+    n = jnp.minimum(live_lanes(clen, ps),
+                    jnp.where(q_pos_max >= 0, q_pos_max // ps + 1, 0))
+    return jnp.where((qi + 1) * bq > s - nl, n, 0)
 
 
 def _make_chunk_attn_kernel(quantized: bool):
@@ -107,42 +129,48 @@ def _make_chunk_attn_kernel(quantized: bool):
         g = h // kvh
         scale = 1.0 / math.sqrt(hd)
 
-        page = pi_ref[b, p]
         clen = cl_ref[b]
         nl = nl_ref[b]
-        # absolute positions: queries are the chunk's right-aligned columns,
-        # keys are this page's slots; invalid lanes / padding columns masked
-        col = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
-        q_pos = clen - s_total + col                       # (bq, 1)
-        valid_q = (col >= s_total - nl) & (q_pos >= 0)
-        t_pos = p * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-        valid = (t_pos < clen) & (page >= 0) & (t_pos <= q_pos) & valid_q
-        if g > 1:                                          # (g * bq, ps)
-            valid = jnp.concatenate([valid] * g, axis=0)
-        valid = valid[None]                                # (1, g*bq, ps)
 
-        # (H, bq, hd) -> (KVH, g * bq, hd): heads grouped by their kv head
-        qh = q_ref[0].astype(jnp.float32).reshape(kvh, g * bq, hd)
-        if quantized:
-            k = k_ref[0].astype(jnp.float32) * ks_ref[0][None]
-            v = v_ref[0].astype(jnp.float32) * vs_ref[0][None]
-        else:
-            k = k_ref[0].astype(jnp.float32)               # (ps, KVH, hd)
-            v = v_ref[0].astype(jnp.float32)
-        s = jnp.einsum("kqd,skd->kqs", qh, k,
-                       preferred_element_type=jnp.float32) * scale
-        s = jnp.where(valid, s, -jnp.inf)                  # (KVH, g*bq, ps)
+        @pl.when(p < _needed_lanes(clen, nl, qi, s_total, bq, ps))
+        def _attend():
+            page = pi_ref[b, p]
+            # absolute positions: queries are the chunk's right-aligned
+            # columns, keys are this page's slots; invalid slots / padding
+            # columns masked
+            col = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+            q_pos = clen - s_total + col                   # (bq, 1)
+            valid_q = (col >= s_total - nl) & (q_pos >= 0)
+            t_pos = p * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+            valid = (t_pos < clen) & (page >= 0) & (t_pos <= q_pos) & valid_q
+            if g > 1:                                      # (g * bq, ps)
+                valid = jnp.concatenate([valid] * g, axis=0)
+            valid = valid[None]                            # (1, g*bq, ps)
 
-        m_prev = m_ref[...]                                # (KVH, g*bq, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        pexp = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
-        corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(pexp, axis=2, keepdims=True)
-        pv = jnp.einsum("kqs,skd->kqd", pexp, v,
-                        preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr + pv
-        m_ref[...] = m_new
+            # (H, bq, hd) -> (KVH, g * bq, hd): heads grouped by kv head
+            qh = q_ref[0].astype(jnp.float32).reshape(kvh, g * bq, hd)
+            if quantized:
+                k = k_ref[0].astype(jnp.float32) * ks_ref[0][None]
+                v = v_ref[0].astype(jnp.float32) * vs_ref[0][None]
+            else:
+                k = k_ref[0].astype(jnp.float32)           # (ps, KVH, hd)
+                v = v_ref[0].astype(jnp.float32)
+            s = jnp.einsum("kqd,skd->kqs", qh, k,
+                           preferred_element_type=jnp.float32) * scale
+            s = jnp.where(valid, s, -jnp.inf)              # (KVH, g*bq, ps)
+
+            m_prev = m_ref[...]                            # (KVH, g*bq, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+            m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+            pexp = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
+            corr = jnp.where(jnp.isfinite(m_prev),
+                             jnp.exp(m_prev - m_safe), 0.0)
+            l_ref[...] = l_ref[...] * corr \
+                + jnp.sum(pexp, axis=2, keepdims=True)
+            pv = jnp.einsum("kqs,skd->kqd", pexp, v,
+                            preferred_element_type=jnp.float32)
+            acc_ref[...] = acc_ref[...] * corr + pv
+            m_ref[...] = m_new
 
         @pl.when(p == n_p - 1)
         def _emit():
@@ -171,11 +199,17 @@ def _chunk_attn_common(q, kv_operands, page_idx, cache_len, new_lens,
         kv_operands = kv_operands[:2] + tuple(
             x[:, :, None] for x in kv_operands[2:])
 
-    def kv_map(bi, qi, p, idx_ref, cl_ref, nl_ref):
-        return (jnp.maximum(idx_ref[bi, p], 0), 0, 0, 0)
+    def page(bi, qi, p, idx_ref, cl_ref, nl_ref):
+        # a dead lane names the last needed lane's page: no new DMA
+        last = jnp.maximum(
+            _needed_lanes(cl_ref[bi], nl_ref[bi], qi, s, bq, ps) - 1, 0)
+        return jnp.maximum(idx_ref[bi, jnp.minimum(p, last)], 0)
 
-    def scale_map(bi, qi, p, idx_ref, cl_ref, nl_ref):
-        return (jnp.maximum(idx_ref[bi, p], 0), 0, 0)
+    def kv_map(*a):
+        return (page(*a), 0, 0, 0)
+
+    def scale_map(*a):
+        return (page(*a), 0, 0)
 
     def q_map(bi, qi, p, idx_ref, cl_ref, nl_ref):
         return (bi, 0, qi, 0)

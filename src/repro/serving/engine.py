@@ -1046,8 +1046,14 @@ class ServingEngine:
             if self._w_step is not None:
                 self._w_step.observe(dt)
         if _TR.enabled:
+            # the decode kernel's grid, and the lanes of it that fetch and
+            # compute: each row walks its ceil(len / page_size) live lanes
+            sc = self.sched_cfg
+            ps = sc.page_size
             _TR.emit_span("engine", "decode_step", t0, dur_ns=dt,
-                          batch=len(slots), tick=self._tick)
+                          batch=len(slots), tick=self._tick,
+                          lanes_live=sum(-(-st.pos // ps) for st in slots),
+                          lanes_grid=sc.max_slots * sc.lanes)
             self._emit_step_phases(t0, t1, t2, t3, t4)
         done = [st for st in slots
                 if self.scheduler.on_token(st, int(toks[st.row]))]

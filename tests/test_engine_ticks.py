@@ -2,7 +2,9 @@
 its ``sched_tick`` (and the lease, dispatch and read-back phases inside
 the step span) under the same ``tick``; a run of idle iterations is one
 ``sched_idle`` span with no phase spans; and each step tick and each idle
-run puts one clock anchor on the profiler's timeline."""
+run puts one clock anchor on the profiler's timeline.  Each decode step
+carries the decode kernel's grid and the lanes of it the rows' lengths
+make live."""
 
 from __future__ import annotations
 
@@ -13,6 +15,7 @@ import time
 
 import jax
 import numpy as np
+import pytest
 from jax.sharding import Mesh
 
 from repro import configs
@@ -27,12 +30,18 @@ PHASES = ("tick_compact", "tick_admit", "tick_grow", "lease_acquire",
 IN_STEP = ("lease_acquire", "step_dispatch", "lease_release",
            "step_readback")
 IDLE_POLL_S = 0.02
+SC = SchedulerConfig(max_slots=4, page_size=8, max_seq=160,
+                     prefill_chunk=16, prefill_rows=2, token_budget=32)
+MAX_NEW = 4
+
+
+def _prompt_len(rid: int) -> int:
+    return 12 + 9 * rid
 
 
 def _serve_traced(trace_dir: str):
     cfg = configs.get_smoke("minicpm-2b")      # the benchmark's CPU widths
-    sc = SchedulerConfig(max_slots=4, page_size=8, max_seq=160,
-                         prefill_chunk=16, prefill_rows=2, token_budget=32)
+    sc = SC
     eng = ServingEngine(
         cfg, M.init_params(jax.random.PRNGKey(0), cfg),
         mesh=Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
@@ -42,8 +51,9 @@ def _serve_traced(trace_dir: str):
     rng = np.random.default_rng(0)
 
     def serve(rids, compact=False):
-        reqs = [Request(rid=i, prompt=rng.integers(1, cfg.vocab, 12 + 9 * i)
-                        .astype(np.int32), max_new=4) for i in rids]
+        reqs = [Request(rid=i, prompt=rng.integers(1, cfg.vocab,
+                                                   _prompt_len(i))
+                        .astype(np.int32), max_new=MAX_NEW) for i in rids]
         for r in reqs:
             eng.submit(r)
         if compact:                 # serviced by a tick of these requests
@@ -75,8 +85,15 @@ def _anchors(trace_dir: str) -> int:
                if e.name == CLOCK_ANCHOR)
 
 
-def test_tick_phases_nest_and_an_idle_run_is_one_span(tmp_path):
-    events = _serve_traced(str(tmp_path))
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """-> (the run's engine and req events, its profiler trace dir)."""
+    trace_dir = str(tmp_path_factory.mktemp("ticks"))
+    return _serve_traced(trace_dir), trace_dir
+
+
+def test_tick_phases_nest_and_an_idle_run_is_one_span(traced):
+    events, trace_dir = traced
     spans = collections.defaultdict(list)
     for e in events:
         if e.cat == "engine" and e.dur_ns:
@@ -122,4 +139,27 @@ def test_tick_phases_nest_and_an_idle_run_is_one_span(tmp_path):
     assert after[0].args["polls"] >= 2         # many polls, one span
     assert after[0].args["tick"] == last.args["tick"] + 1
     # one clock anchor per step tick and per idle run
-    assert _anchors(str(tmp_path)) == len(ticks) + len(idle)
+    assert _anchors(trace_dir) == len(ticks) + len(idle)
+
+
+def test_decode_steps_count_the_live_lanes_of_the_grid(traced):
+    """``lanes_grid`` is max_slots x lanes on every decode step, and
+    ``lanes_live`` sums ceil(length / page_size) over the step's rows: a
+    request of prompt L takes decode steps at lengths L + 1 .. L + max_new
+    - 1, so over the run the counts add up to those lanes of every
+    request."""
+    events, _ = traced
+    steps = [e for e in events if e.name == "decode_step"]
+    assert steps
+    grid = SC.max_slots * SC.lanes
+    ps = SC.page_size
+    for e in steps:
+        assert e.args["lanes_grid"] == grid
+        assert e.args["batch"] <= e.args["lanes_live"] \
+            <= e.args["batch"] * SC.lanes
+    rids = {e.args["rid"] for e in events if e.name == "first_token"}
+    assert rids == set(range(5))
+    assert not [e for e in events if e.name == "evict"]
+    want = sum(-(-(_prompt_len(r) + k) // ps)
+               for r in rids for k in range(1, MAX_NEW))
+    assert sum(e.args["lanes_live"] for e in steps) == want
