@@ -86,8 +86,9 @@ def _chunk_case(rng, b=4, s=8, h=8, kvh=2, hd=16, n_pages=64, ps=4,
         off += npg
         cache_len[i] = clen
         new_lens[i] = nl
-    return (q, kp, vp) + tuple(map(jnp.asarray,
-                                   (page_idx, cache_len, new_lens)))
+    # a one-layer store in the store's lane-dense layout, and its layer
+    return (q, R.one_layer(kp), R.one_layer(vp), jnp.int32(0)) + tuple(
+        map(jnp.asarray, (page_idx, cache_len, new_lens)))
 
 
 # ---------------------------------------------------------------------------
@@ -98,14 +99,14 @@ def _chunk_case(rng, b=4, s=8, h=8, kvh=2, hd=16, n_pages=64, ps=4,
 def bench_correctness() -> dict:
     """Streaming kernel vs oracle (the CI smoke gate)."""
     rng = np.random.default_rng(0)
-    q, kp, vp, pi, cl, nl = _chunk_case(rng)
-    out_k = np.asarray(K.paged_chunk_attention(q, kp, vp, pi, cl, nl))
-    out_r = np.asarray(jax.jit(R.paged_chunk_attn_ref)(q, kp, vp, pi, cl,
-                                                       nl))
+    q, kp, vp, ly, pi, cl, nl = _chunk_case(rng)
+    out_k = np.asarray(K.paged_chunk_attention(q, kp, vp, ly, pi, cl, nl))
+    out_r = np.asarray(jax.jit(R.paged_chunk_attn_ref)(q, kp, vp, ly, pi,
+                                                       cl, nl))
     check(np.array_equal(out_k, out_r),
           "paged_chunk_attention == paged_chunk_attn_ref (bit-exact)")
-    dense = np.asarray(jax.jit(R.paged_chunk_dense_ref)(q, kp, vp, pi, cl,
-                                                        nl))
+    dense = np.asarray(jax.jit(R.paged_chunk_dense_ref)(q, kp, vp, ly, pi,
+                                                        cl, nl))
     check(bool(np.allclose(out_k, dense, atol=1e-5)),
           "paged_chunk_attention ~= dense gather formulation")
     check(np.array_equal(out_k[-1], np.zeros_like(out_k[-1])),
@@ -120,14 +121,15 @@ def bench_materialization() -> dict:
     kernel's HLO must not."""
     from repro.analysis import lint_hlo as L
     rng = np.random.default_rng(1)
-    q, kp, vp, pi, cl, nl = _chunk_case(rng)
+    q, kp, vp, ly, pi, cl, nl = _chunk_case(rng)
     b, lanes = pi.shape
-    _, ps, kvh, hd = kp.shape
-    dense_kv = (b, lanes * ps, kvh, hd)
+    hd = q.shape[-1]
+    _, _, ps, kv_lanes = kp.shape
+    dense_kv = (b, lanes * ps, kv_lanes // hd, hd)
     dense_hlo = jax.jit(R.paged_chunk_dense_ref).lower(
-        q, kp, vp, pi, cl, nl).as_text()
+        q, kp, vp, ly, pi, cl, nl).as_text()
     streamed_hlo = jax.jit(K.paged_chunk_attention).lower(
-        q, kp, vp, pi, cl, nl).as_text()
+        q, kp, vp, ly, pi, cl, nl).as_text()
     check(L.find_shape(dense_hlo, dense_kv),
           f"dense path materializes a {dense_kv} KV buffer (sanity)")
     findings = L.lint_step("paged_chunk_attention", streamed_hlo,
@@ -145,10 +147,11 @@ def bench_transfers() -> dict:
     """Chunk attention with device-resident operands moves zero bytes of
     KV between host and device."""
     rng = np.random.default_rng(2)
-    q, kp, vp, pi, cl, nl = _chunk_case(rng)
+    q, kp, vp, ly, pi, cl, nl = _chunk_case(rng)
 
     def step():
-        K.paged_chunk_attention(q, kp, vp, pi, cl, nl).block_until_ready()
+        K.paged_chunk_attention(q, kp, vp, ly, pi, cl,
+                                nl).block_until_ready()
 
     step()                                 # warmup / compile
     guard_ok = True
@@ -248,16 +251,16 @@ def bench_timing() -> dict:
     rng = np.random.default_rng(3)
     dense = jax.jit(R.paged_chunk_dense_ref)
     for s, lanes in ((8, 16), (32, 16), (64, 32)):
-        q, kp, vp, pi, cl, nl = _chunk_case(
+        q, kp, vp, ly, pi, cl, nl = _chunk_case(
             rng, b=8, s=s, h=8, kvh=2, hd=32, n_pages=8 * lanes + 8,
             ps=8, lanes=lanes)
 
         def run_stream():
-            K.paged_chunk_attention(q, kp, vp, pi, cl,
+            K.paged_chunk_attention(q, kp, vp, ly, pi, cl,
                                     nl).block_until_ready()
 
         def run_dense():
-            dense(q, kp, vp, pi, cl, nl).block_until_ready()
+            dense(q, kp, vp, ly, pi, cl, nl).block_until_ready()
 
         out[f"S={s},lanes={lanes}"] = {
             "streamed_us": round(timeit(run_stream, 20) * 1e6, 1),

@@ -126,19 +126,27 @@ def _decode_case(seed, b=8, h=4, kvh=2, hd=32, ps=PAGE_SIZE, lanes=LANES,
 
 
 ERR_BOUND = 0.05      # gated attention-output envelope, unit-variance in
+L0 = jnp.int32(0)     # the one layer of the cases' one-layer stores
+
+
+def _stores(k, v):
+    """(n_pages, ps, KVH, hd) pages -> one-layer lane-dense stores: (k, v,
+    kq, vq, ks, vs), float and int8 with its scales."""
+    kq, ks = map(R.one_layer, quantize_pages(k))
+    vq, vs = map(R.one_layer, quantize_pages(v))
+    return R.one_layer(k), R.one_layer(v), kq, vq, ks, vs
 
 
 def bench_error() -> dict:
     worst_vs_fp32 = worst_vs_qref = 0.0
     for seed in (0, 1):
         q, k, v, pi, cl = _decode_case(seed)
-        kq, ks = quantize_pages(k)
-        vq, vs = quantize_pages(v)
-        out = np.asarray(K.paged_attention_quant(q, kq, vq, ks, vs, pi,
+        k, v, kq, vq, ks, vs = _stores(k, v)
+        out = np.asarray(K.paged_attention_quant(q, kq, vq, ks, vs, L0, pi,
                                                  cl))
         qref = np.asarray(jax.jit(R.paged_attn_quant_ref)(
-            q, kq, vq, ks, vs, pi, cl))
-        ref32 = np.asarray(jax.jit(R.paged_attn_ref)(q, k, v, pi, cl))
+            q, kq, vq, ks, vs, L0, pi, cl))
+        ref32 = np.asarray(jax.jit(R.paged_attn_ref)(q, k, v, L0, pi, cl))
         worst_vs_qref = max(worst_vs_qref,
                             float(np.max(np.abs(out - qref))))
         worst_vs_fp32 = max(worst_vs_fp32,
@@ -175,21 +183,21 @@ def bench_error() -> dict:
 def bench_latency(smoke: bool) -> dict:
     iters = 3 if smoke else 10
     q, k, v, pi, cl = _decode_case(3)
-    kq, ks = quantize_pages(k)
-    vq, vs = quantize_pages(v)
-    t16 = timeit(lambda: K.paged_attention(q, k, v, pi, cl)
+    k, v, kq, vq, ks, vs = _stores(k, v)
+    t16 = timeit(lambda: K.paged_attention(q, k, v, L0, pi, cl)
                  .block_until_ready(), iters)
-    t8 = timeit(lambda: K.paged_attention_quant(q, kq, vq, ks, vs, pi, cl)
-                .block_until_ready(), iters)
+    t8 = timeit(lambda: K.paged_attention_quant(q, kq, vq, ks, vs, L0, pi,
+                                                cl).block_until_ready(),
+                iters)
     s = 8
     r = np.random.default_rng(4)
     qc = jnp.asarray(r.standard_normal((4, s, 4, 32)), jnp.float32)
     nl = jnp.minimum(cl[:4], s)
-    c16 = timeit(lambda: K.paged_chunk_attention(qc, k, v, pi[:4], cl[:4],
-                                                 nl).block_until_ready(),
-                 iters)
+    c16 = timeit(lambda: K.paged_chunk_attention(
+        qc, k, v, L0, pi[:4], cl[:4], nl).block_until_ready(), iters)
     c8 = timeit(lambda: K.paged_chunk_attention_quant(
-        qc, kq, vq, ks, vs, pi[:4], cl[:4], nl).block_until_ready(), iters)
+        qc, kq, vq, ks, vs, L0, pi[:4], cl[:4], nl).block_until_ready(),
+        iters)
     return {
         "decode_fp32_us": round(t16 * 1e6, 1),
         "decode_quant_us": round(t8 * 1e6, 1),

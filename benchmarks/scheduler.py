@@ -105,8 +105,9 @@ def bench_correctness() -> dict:
         cache_len[i] = int(rng.integers(1, npg * ps + 1))
     cache_len[2] = 0
     pi, cl = jnp.asarray(page_idx), jnp.asarray(cache_len)
-    out_k = np.asarray(K.paged_attention(q, kp, vp, pi, cl))
-    out_r = np.asarray(jax.jit(R.paged_attn_ref)(q, kp, vp, pi, cl))
+    store = R.one_layer(kp), R.one_layer(vp), jnp.int32(0)
+    out_k = np.asarray(K.paged_attention(q, *store, pi, cl))
+    out_r = np.asarray(jax.jit(R.paged_attn_ref)(q, *store, pi, cl))
     check(np.array_equal(out_k, out_r),
           "paged_attention == paged_attn_ref (bit-exact)")
     check(np.array_equal(out_k[2], np.zeros_like(out_k[2])),

@@ -124,13 +124,13 @@ def require_kernels(program: str, hlo_text: str, want: Sequence[str]) -> None:
 def pool_pages(devices: Sequence[jax.Device], page_bytes: int,
                want: int) -> int:
     """Pages the KV store gets: ``want`` (half again what the traffic can
-    hold at once) unless HBM is shorter.  The store must fit TWICE beside
-    what is already resident — a step's layer scan writes its updated
-    store into a second buffer before the donated one is freed
-    (``memory_analysis`` temp ~ the store's size) — plus a reserve."""
+    hold at once) unless HBM is shorter.  The store must fit once beside
+    what is already resident, plus a reserve: the steps carry the donated
+    store through the layer scan and write it in place, so they hold no
+    second copy of it."""
     free = min(d.memory_stats()["bytes_limit"]
                - d.memory_stats()["bytes_in_use"] for d in devices)
-    fit = (free - HBM_RESERVE) // (2 * page_bytes)
+    fit = (free - HBM_RESERVE) // page_bytes
     if fit < want * 2 // 3:
         raise RuntimeError(f"HBM fits {fit} KV pages of {page_bytes} B; the "
                            f"traffic holds up to {want * 2 // 3} at once")

@@ -16,9 +16,9 @@ lease-held steps) and ``benchmarks/device_bravo.py`` / ``registry.py``
   the paged kernels stream pages instead of gathering them.
 * ``fp32-page-materialization`` — a *quantized*-store step must keep the
   pool int8 end to end: the lowering must not hold a float32 buffer of
-  the pool's page shape (per-layer slice or full store) — dequantization
-  happens per block inside the kernel at DMA time, never as a whole-pool
-  upcast.
+  the pool's lane-dense page shape (a layer's pages or the full store) —
+  dequantization happens per block inside the kernel, never as a
+  whole-pool upcast.
 * ``missing-donation`` — buffers the engine declares donated
   (``donate_argnums``) must actually alias in the lowering.  The engine's
   ``jit_step`` disables donation on CPU (XLA:CPU ignores it), so the lint
@@ -43,6 +43,7 @@ from .hlo import parse_hlo
 __all__ = [
     "Finding",
     "find_shape",
+    "pool_fp32_shapes",
     "find_transfers",
     "has_donation",
     "lint_step",
@@ -136,6 +137,15 @@ def lint_step(name: str, lowered: str, compiled: Optional[str] = None,
 # ---------------------------------------------------------------------------
 
 
+def pool_fp32_shapes(cfg, n_pages: int, page_size: int) -> List[tuple]:
+    """The float32 twins of the int8 page store
+    (``models.model.init_paged_caches``): one layer's pages ``(n_pages,
+    page_size, KVH * hd)`` and the whole store ``(L, n_pages, page_size,
+    KVH * hd)``."""
+    page = (n_pages, page_size, cfg.n_kv_heads * cfg.hd)
+    return [page, (cfg.n_layers,) + page]
+
+
 def serving_steps(cfg=None, compile_steps: bool = True) -> Dict[str, dict]:
     """Build + lower (+ compile) every jitted serving step at the smoke
     config.  Returns ``{name: kwargs-for-lint_step}``.
@@ -169,12 +179,10 @@ def serving_steps(cfg=None, compile_steps: bool = True) -> Dict[str, dict]:
     paged_kv = M.init_paged_caches(cfg, n_pages, page_size)
     paged_kv_q = M.init_paged_caches(cfg, n_pages, page_size,
                                      quantized=True)
-    # the int8 pool's fp32 twins: a quantized step holding either one has
-    # dequantized outside the kernel
-    pool_fp32 = [
-        (n_pages, page_size, cfg.n_kv_heads, cfg.hd),
-        (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.hd),
-    ]
+    # the int8 pool's fp32 twins, in the store's lane-dense page layout: a
+    # quantized step holding either one (a layer's pages or the whole
+    # store) has dequantized outside the kernel
+    pool_fp32 = pool_fp32_shapes(cfg, n_pages, page_size)
     caches = M.init_caches(cfg, B, lanes * page_size)
     tokens = jnp.zeros((B, T), jnp.int32)
     token = jnp.zeros((B, 1), jnp.int32)

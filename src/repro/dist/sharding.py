@@ -359,12 +359,14 @@ def kv_head_axis(n_kv_heads: int, mesh: Mesh) -> Optional[str]:
 
 def page_store_specs(store: Any, n_kv_heads: int, mesh: Mesh) -> Any:
     """Specs for the paged KV store (``models.model.init_paged_caches``):
-    pages ``(L, n_pages, ps, KVH, hd)`` and per-page scales ``(L, n_pages,
-    KVH)`` split their KV-head dim per :func:`kv_head_axis`, everything
-    else replicates — the pool is shared by every request, so it never
-    splits over the data axes."""
+    lane-dense pages ``(L, n_pages, ps, KVH * hd)`` split their lane dim
+    and per-page scales ``(L, n_pages, KVH)`` their KV-head dim per
+    :func:`kv_head_axis` (a KV head's lanes are contiguous, so an even
+    split hands each shard whole KV heads), everything else replicates —
+    the pool is shared by every request, so it never splits over the data
+    axes."""
     ax = kv_head_axis(n_kv_heads, mesh)
-    return {k: P(None, None, None, ax, None) if x.ndim == 5
+    return {k: P(None, None, None, ax) if x.ndim == 4
             else P(None, None, ax) for k, x in store.items()}
 
 

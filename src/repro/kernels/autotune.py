@@ -110,33 +110,35 @@ def _time(fn: Callable[[], jax.Array], repeats: int) -> float:
 
 def _candidates(seed: int, s: int) -> Dict[str, Tuple[str, List[int], dict]]:
     q, k, v, pi, cl = _decode_case(seed)
-    kq, ks = quantize_pages(k)
-    vq, vs = quantize_pages(v)
+    kq, ks = map(ref.one_layer, quantize_pages(k))
+    vq, vs = map(ref.one_layer, quantize_pages(v))
+    k, v = ref.one_layer(k), ref.one_layer(v)
+    ly = jnp.int32(0)
     cq, _, _, _, _, cnl = _chunk_case(seed, s=s)
-    dec_ref = ref.paged_attn_ref(q, k, v, pi, cl)
-    dec_qref = ref.paged_attn_quant_ref(q, kq, vq, ks, vs, pi, cl)
-    chk_ref = ref.paged_chunk_attn_ref(cq, k, v, pi, cl, cnl)
-    chk_qref = ref.paged_chunk_attn_quant_ref(cq, kq, vq, ks, vs, pi, cl,
-                                              cnl)
+    dec_ref = ref.paged_attn_ref(q, k, v, ly, pi, cl)
+    dec_qref = ref.paged_attn_quant_ref(q, kq, vq, ks, vs, ly, pi, cl)
+    chk_ref = ref.paged_chunk_attn_ref(cq, k, v, ly, pi, cl, cnl)
+    chk_qref = ref.paged_chunk_attn_quant_ref(cq, kq, vq, ks, vs, ly, pi,
+                                              cl, cnl)
     it = _interpret()
     bq_cands = [0] + [d for d in (4, 8, 16) if s % d == 0 and d <= s]
     return {
         "paged_attn": ("lanes_per_step", [1, 2, 4], dict(
-            fn=lambda n: _paged_attn_call(q, k, v, pi, cl, interpret=it,
+            fn=lambda n: _paged_attn_call(q, k, v, ly, pi, cl, interpret=it,
                                           lanes_per_step=n),
             oracle=dec_ref, tol=1e-5)),
         "paged_attn_quant": ("lanes_per_step", [1, 2, 4], dict(
-            fn=lambda n: _paged_attn_quant_call(q, kq, vq, ks, vs, pi, cl,
-                                                interpret=it,
+            fn=lambda n: _paged_attn_quant_call(q, kq, vq, ks, vs, ly, pi,
+                                                cl, interpret=it,
                                                 lanes_per_step=n),
             oracle=dec_qref, tol=1e-5)),
         "paged_chunk_attn": ("block_q", bq_cands, dict(
-            fn=lambda n: _chunk_attn_call(cq, k, v, pi, cl, cnl,
+            fn=lambda n: _chunk_attn_call(cq, k, v, ly, pi, cl, cnl,
                                           interpret=it, block_q=n),
             oracle=chk_ref, tol=1e-5)),
         "paged_chunk_attn_quant": ("block_q", bq_cands, dict(
-            fn=lambda n: _chunk_attn_quant_call(cq, kq, vq, ks, vs, pi, cl,
-                                                cnl, interpret=it,
+            fn=lambda n: _chunk_attn_quant_call(cq, kq, vq, ks, vs, ly, pi,
+                                                cl, cnl, interpret=it,
                                                 block_q=n),
             oracle=chk_qref, tol=1e-5)),
     }

@@ -145,15 +145,17 @@ def fused_publish_multi(table2d: jax.Array, rbias_vec: jax.Array,
 
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                    page_idx: jax.Array, cache_len: jax.Array) -> jax.Array:
+                    layer: jax.Array, page_idx: jax.Array,
+                    cache_len: jax.Array) -> jax.Array:
     """Gather-by-page decode attention over the KV pool's page store.
 
-    q: (B, H, hd); k/v_pages: (n_pages, page_size, KVH, hd); page_idx:
-    (B, P) int32 page-index vectors (-1 = unused lane); cache_len: (B,)
-    valid lengths.  -> (B, H, hd).  Each request's pages stream through
-    VMEM via scalar-prefetched block indices — the dense (B, S, KVH, hd)
-    cache is never materialized."""
-    return _paged_attn_call(q, k_pages, v_pages, page_idx, cache_len,
+    q: (B, H, hd); k/v_pages: (L, n_pages, page_size, KVH * hd), the whole
+    store; layer: int32 scalar; page_idx: (B, P) int32 page-index vectors
+    (-1 = unused lane); cache_len: (B,) valid lengths.  -> (B, H, hd).
+    Each request's pages of ``layer`` stream through VMEM via
+    scalar-prefetched block indices — the dense (B, S, KVH, hd) cache is
+    never materialized."""
+    return _paged_attn_call(q, k_pages, v_pages, layer, page_idx, cache_len,
                             interpret=_interpret(),
                             lanes_per_step=_tuned("paged_attn",
                                                   "lanes_per_step", 1))
@@ -161,50 +163,51 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
 
 def paged_attention_quant(q: jax.Array, k_pages: jax.Array,
                           v_pages: jax.Array, k_scale: jax.Array,
-                          v_scale: jax.Array, page_idx: jax.Array,
+                          v_scale: jax.Array, layer: jax.Array,
+                          page_idx: jax.Array,
                           cache_len: jax.Array) -> jax.Array:
     """Quantized-pool decode attention: same contract as
-    :func:`paged_attention` with int8 k/v_pages and (n_pages, KVH) float32
-    per-page scales (``kernels.quant`` layout); pages dequantize inside
-    the kernel at DMA time — no fp32 page copy is ever materialized."""
+    :func:`paged_attention` with int8 k/v_pages and (L, n_pages, KVH)
+    float32 per-page scales (``kernels.quant`` layout); the scales apply
+    inside the kernel — no fp32 page copy is ever materialized."""
     return _paged_attn_quant_call(
-        q, k_pages, v_pages, k_scale, v_scale, page_idx, cache_len,
+        q, k_pages, v_pages, k_scale, v_scale, layer, page_idx, cache_len,
         interpret=_interpret(),
         lanes_per_step=_tuned("paged_attn_quant", "lanes_per_step", 1))
 
 
 def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
-                          v_pages: jax.Array, page_idx: jax.Array,
-                          cache_len: jax.Array,
+                          v_pages: jax.Array, layer: jax.Array,
+                          page_idx: jax.Array, cache_len: jax.Array,
                           new_lens: jax.Array) -> jax.Array:
     """Streaming chunk-prefill attention over the KV pool's page store.
 
-    q: (B, S, H, hd) right-aligned prompt chunks; k/v_pages: (n_pages,
-    page_size, KVH, hd); page_idx: (B, P) int32 (-1 = unused lane);
-    cache_len: (B,) total valid length AFTER the chunk; new_lens: (B,)
-    valid trailing columns per row.  -> (B, S, H, hd), padding columns
-    zero.  Pages stream through VMEM via scalar-prefetched block indices —
-    the dense (B, lanes * page_size, KVH, hd) gather of the PR-4 prefill
-    path is never materialized."""
+    q: (B, S, H, hd) right-aligned prompt chunks; k/v_pages: (L, n_pages,
+    page_size, KVH * hd); layer: int32 scalar; page_idx: (B, P) int32 (-1
+    = unused lane); cache_len: (B,) total valid length AFTER the chunk;
+    new_lens: (B,) valid trailing columns per row.  -> (B, S, H, hd),
+    padding columns zero.  Pages stream through VMEM via scalar-prefetched
+    block indices — the dense (B, lanes * page_size, KVH, hd) gather of
+    the PR-4 prefill path is never materialized."""
     s = q.shape[1]
     bq = _tuned("paged_chunk_attn", "block_q", 0)
-    return _chunk_attn_call(q, k_pages, v_pages, page_idx, cache_len,
+    return _chunk_attn_call(q, k_pages, v_pages, layer, page_idx, cache_len,
                             new_lens, interpret=_interpret(),
                             block_q=bq if bq and s % bq == 0 else 0)
 
 
 def paged_chunk_attention_quant(q: jax.Array, k_pages: jax.Array,
                                 v_pages: jax.Array, k_scale: jax.Array,
-                                v_scale: jax.Array, page_idx: jax.Array,
-                                cache_len: jax.Array,
+                                v_scale: jax.Array, layer: jax.Array,
+                                page_idx: jax.Array, cache_len: jax.Array,
                                 new_lens: jax.Array) -> jax.Array:
     """Quantized-pool chunk-prefill attention: same contract as
-    :func:`paged_chunk_attention` with int8 k/v_pages and (n_pages, KVH)
-    float32 per-page scales; dequantization happens in VMEM."""
+    :func:`paged_chunk_attention` with int8 k/v_pages and (L, n_pages,
+    KVH) float32 per-page scales; dequantization happens in VMEM."""
     s = q.shape[1]
     bq = _tuned("paged_chunk_attn_quant", "block_q", 0)
     return _chunk_attn_quant_call(
-        q, k_pages, v_pages, k_scale, v_scale, page_idx, cache_len,
+        q, k_pages, v_pages, k_scale, v_scale, layer, page_idx, cache_len,
         new_lens, interpret=_interpret(),
         block_q=bq if bq and s % bq == 0 else 0)
 

@@ -13,21 +13,24 @@ Layout and grid
 * ``q``: ``(B, S, H, hd)`` — one RIGHT-ALIGNED prompt chunk per row (row
   i's last ``new_lens[i]`` columns are real tokens; the leading columns are
   padding).  Column ``j``'s absolute position is ``cache_len - S + j``.
-* ``k_pages``/``v_pages``: ``(n_pages, page_size, KVH, hd)`` — the pool's
-  page store, shared by every request.
+* ``k_pages``/``v_pages``: ``(L, n_pages, page_size, KVH * hd)`` — the
+  pool's whole page store, shared by every request, in its one lane-dense
+  layout (``kernels.paged_attn``); ``layer`` picks the layer.
 * grid = ``(B, NQ, P)`` with ``NQ = S / block_q`` query blocks and ``P``
   page lanes: TPU grid steps run sequentially on a core, so the per-(row,
   q-block) softmax state (m/l/acc scratch) accumulates across the ``P``
   inner steps and the output block is emitted at the last page.
 * queries are transposed head-major, ``(B, H, S, hd)``, around the call,
-  so each block's matmuls batch over the leading KV-head dim.  A
+  so a KV head's grouped heads are one leading-dim slice of the block.  A
   token-major ``(bq, H, hd)`` block made Mosaic unroll the matmuls per
   (row, head): minutes of compile at 36 heads, against seconds now.
-* ``page_idx``/``cache_len``/``new_lens`` ride in as **scalar-prefetch**
-  operands (``PrefetchScalarGridSpec``): the index map reads
-  ``page_idx[b, p]`` to pick which page tile the next grid step DMAs — the
-  gather happens in the block-fetch pipeline, never as a materialized
-  ``take``.
+* each KV head reads its static lane slice of the page, ``[c * hd, (c +
+  1) * hd)``: two plain matmuls per KV head, no reshape of the lanes.
+* ``page_idx``/``cache_len``/``new_lens``/``layer`` ride in as
+  **scalar-prefetch** operands (``PrefetchScalarGridSpec``): the index map
+  reads ``page_idx[b, p]`` to pick which page tile the next grid step
+  DMAs — the gather happens in the block-fetch pipeline, never as a
+  materialized ``take``.
 * the walk is bounded per (row, q-block): the block needs the lanes up to
   the smaller of the row's last page, ``ceil(cache_len / page_size)``, and
   its causal end, ``q_pos_max // page_size + 1`` with ``q_pos_max`` the
@@ -35,7 +38,7 @@ Layout and grid
   block of a ``new_lens == 0`` row) needs none.  Every lane past that
   names the block of the last needed lane (lane 0 where none is needed),
   so consecutive dead steps name one block and the pipeline issues no
-  DMA for them, and the kernel body skips their einsums, exps and
+  DMA for them, and the kernel body skips their matmuls, exps and
   accumulator updates.  A skipped lane would have added nothing, so the
   output is unchanged; inside the needed lanes, ``-1`` lanes clamp to
   page 0 and stay masked out of the softmax.
@@ -63,7 +66,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .paged_attn import live_lanes
+from .paged_attn import NT, live_lanes
 
 
 def _pick_block_q(s: int, limit: int = 32) -> int:
@@ -94,20 +97,21 @@ def _needed_lanes(clen, nl, qi, s: int, bq: int, ps: int):
 
 
 def _make_chunk_attn_kernel(quantized: bool):
-    """Kernel factory.  ``quantized``: the page blocks are int8 and each is
-    followed by its (1, KVH, 1) float32 per-page scale block (fetched
-    through the SAME page-index map); dequantization is one cast +
-    broadcast multiply at DMA time, inside VMEM — no fp32 copy of any page
-    ever exists outside the kernel.
+    """Kernel factory.  ``quantized``: the page blocks are int8, and the
+    layer's float32 scales, ``(n_pages, KVH)``, sit whole in VMEM (one
+    block for the whole grid, as in ``kernels.paged_attn``);
+    dequantization is one cast + scalar multiply per KV head's lanes,
+    inside VMEM — no fp32 copy of any page ever exists outside the
+    kernel.
 
-    Queries arrive head-major, ``(H, bq, hd)`` per block, so every matmul
-    is one batched dot over the KV heads with the batch dim leading: the
-    query-block rows of a KV head's ``g`` grouped heads stack into one
-    ``(g * bq, hd)`` operand."""
+    Queries arrive head-major, ``(H, bq, hd)`` per block.  KV head ``c``
+    is a static lane slice ``[c * hd, (c + 1) * hd)`` of the lane-dense
+    page; the query-block rows of its ``g`` grouped heads stack into one
+    ``(g * bq, hd)`` operand, so each KV head costs two plain matmuls."""
 
-    def kernel(pi_ref, cl_ref, nl_ref, q_ref, *refs):
+    def kernel(pi_ref, cl_ref, nl_ref, ly_ref, q_ref, *refs):
         if quantized:
-            k_ref, v_ref, ks_ref, vs_ref = refs[:4]
+            ks_ref, vs_ref, k_ref, v_ref = refs[:4]
         else:
             k_ref, v_ref = refs[:2]
         o_ref, m_ref, l_ref, acc_ref = refs[-4:]
@@ -122,8 +126,9 @@ def _make_chunk_attn_kernel(quantized: bool):
             l_ref[...] = jnp.zeros_like(l_ref)
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        ps, kvh, hd = k_ref.shape[1], k_ref.shape[2], k_ref.shape[3]
-        h, bq = q_ref.shape[1], q_ref.shape[2]
+        ps = k_ref.shape[0]
+        h, bq, hd = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
+        kvh = k_ref.shape[1] // hd
         n_q = pl.num_programs(1)
         s_total = bq * n_q
         g = h // kvh
@@ -145,32 +150,33 @@ def _make_chunk_attn_kernel(quantized: bool):
             valid = (t_pos < clen) & (page >= 0) & (t_pos <= q_pos) & valid_q
             if g > 1:                                      # (g * bq, ps)
                 valid = jnp.concatenate([valid] * g, axis=0)
-            valid = valid[None]                            # (1, g*bq, ps)
 
-            # (H, bq, hd) -> (KVH, g * bq, hd): heads grouped by kv head
-            qh = q_ref[0].astype(jnp.float32).reshape(kvh, g * bq, hd)
-            if quantized:
-                k = k_ref[0].astype(jnp.float32) * ks_ref[0][None]
-                v = v_ref[0].astype(jnp.float32) * vs_ref[0][None]
-            else:
-                k = k_ref[0].astype(jnp.float32)           # (ps, KVH, hd)
-                v = v_ref[0].astype(jnp.float32)
-            s = jnp.einsum("kqd,skd->kqs", qh, k,
-                           preferred_element_type=jnp.float32) * scale
-            s = jnp.where(valid, s, -jnp.inf)              # (KVH, g*bq, ps)
+            for c in range(kvh):
+                lanes = slice(c * hd, (c + 1) * hd)
+                # (g, bq, hd) -> (g * bq, hd): the heads of KV head c
+                qh = q_ref[0, c * g:(c + 1) * g].astype(
+                    jnp.float32).reshape(g * bq, hd)
+                k = k_ref[:, lanes].astype(jnp.float32)    # (ps, hd)
+                v = v_ref[:, lanes].astype(jnp.float32)
+                if quantized:         # the page's scale of KV head c
+                    k = k * ks_ref[jnp.maximum(page, 0), c]
+                    v = v * vs_ref[jnp.maximum(page, 0), c]
+                s = jax.lax.dot_general(
+                    qh, k, NT, preferred_element_type=jnp.float32) * scale
+                s = jnp.where(valid, s, -jnp.inf)          # (g * bq, ps)
 
-            m_prev = m_ref[...]                            # (KVH, g*bq, 1)
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-            m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-            pexp = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
-            corr = jnp.where(jnp.isfinite(m_prev),
-                             jnp.exp(m_prev - m_safe), 0.0)
-            l_ref[...] = l_ref[...] * corr \
-                + jnp.sum(pexp, axis=2, keepdims=True)
-            pv = jnp.einsum("kqs,skd->kqd", pexp, v,
-                            preferred_element_type=jnp.float32)
-            acc_ref[...] = acc_ref[...] * corr + pv
-            m_ref[...] = m_new
+                m_prev = m_ref[c]                          # (g * bq, 1)
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=1, keepdims=True))
+                m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+                pexp = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
+                corr = jnp.where(jnp.isfinite(m_prev),
+                                 jnp.exp(m_prev - m_safe), 0.0)
+                l_ref[c] = l_ref[c] * corr \
+                    + jnp.sum(pexp, axis=1, keepdims=True)
+                acc_ref[c] = acc_ref[c] * corr + jnp.dot(
+                    pexp, v, preferred_element_type=jnp.float32)
+                m_ref[c] = m_new
 
         @pl.when(p == n_p - 1)
         def _emit():
@@ -180,49 +186,40 @@ def _make_chunk_attn_kernel(quantized: bool):
     return kernel
 
 
-def _chunk_attn_common(q, kv_operands, page_idx, cache_len, new_lens,
+def _chunk_attn_common(q, kv_operands, layer, page_idx, cache_len, new_lens,
                        interpret, block_q):
-    """Shared call-path for the fp32 and quantized kernels.
+    """Shared call-path for the bf16 and quantized kernels.
     ``kv_operands`` is (k_pages, v_pages[, k_scale, v_scale])."""
     b, s, h, hd = q.shape
-    _, ps, kvh, _ = kv_operands[0].shape
+    _, n_pages, ps, lanes = kv_operands[0].shape
     n_p = page_idx.shape[1]
-    assert h % kvh == 0, (h, kvh)
+    kvh = lanes // hd
+    assert lanes % hd == 0 and h % kvh == 0, (h, lanes, hd)
     g = h // kvh
     bq = block_q or _pick_block_q(s)
     assert s % bq == 0, (s, bq)
     n_q = s // bq
     quantized = len(kv_operands) == 4
-    if quantized:     # (n_pages, KVH) -> (n_pages, KVH, 1): a (1, KVH, 1)
-        #               block spans the array's two minor dims, as the TPU
-        #               tiling rule requires
-        kv_operands = kv_operands[:2] + tuple(
-            x[:, :, None] for x in kv_operands[2:])
 
-    def page(bi, qi, p, idx_ref, cl_ref, nl_ref):
+    def block_map(bi, qi, p, idx_ref, cl_ref, nl_ref, ly_ref):
         # a dead lane names the last needed lane's page: no new DMA
         last = jnp.maximum(
             _needed_lanes(cl_ref[bi], nl_ref[bi], qi, s, bq, ps) - 1, 0)
-        return jnp.maximum(idx_ref[bi, jnp.minimum(p, last)], 0)
+        return (ly_ref[0], jnp.maximum(idx_ref[bi, jnp.minimum(p, last)], 0),
+                0, 0)
 
-    def kv_map(*a):
-        return (page(*a), 0, 0, 0)
-
-    def scale_map(*a):
-        return (page(*a), 0, 0)
-
-    def q_map(bi, qi, p, idx_ref, cl_ref, nl_ref):
+    def q_map(bi, qi, p, *_):
         return (bi, 0, qi, 0)
 
-    in_specs = [pl.BlockSpec((1, h, bq, hd), q_map),
-                pl.BlockSpec((1, ps, kvh, hd), kv_map),
-                pl.BlockSpec((1, ps, kvh, hd), kv_map)]
-    if quantized:
-        in_specs += [pl.BlockSpec((1, kvh, 1), scale_map),
-                     pl.BlockSpec((1, kvh, 1), scale_map)]
+    in_specs = [pl.BlockSpec((1, h, bq, hd), q_map)]
+    operands = list(kv_operands[:2])
+    if quantized:     # the layer's scales, whole: (n_pages, KVH)
+        in_specs += [pl.BlockSpec((n_pages, kvh), lambda *_: (0, 0))] * 2
+        operands = [x[layer] for x in kv_operands[2:]] + operands
+    in_specs += [pl.BlockSpec((None, None, ps, lanes), block_map)] * 2
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,            # page_idx, cache_len, new_lens
+        num_scalar_prefetch=4,    # page_idx, cache_len, new_lens, layer
         grid=(b, n_q, n_p),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, h, bq, hd), q_map),
@@ -240,34 +237,38 @@ def _chunk_attn_common(q, kv_operands, page_idx, cache_len, new_lens,
         out_shape=jax.ShapeDtypeStruct((b, h, s, hd), q.dtype),
         interpret=interpret,
     )(page_idx.astype(jnp.int32), cache_len.astype(jnp.int32),
-      new_lens.astype(jnp.int32), q.transpose(0, 2, 1, 3), *kv_operands)
+      new_lens.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+      q.transpose(0, 2, 1, 3), *operands)
     return out.transpose(0, 2, 1, 3)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_q"))
 def _chunk_attn_call(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                     page_idx: jax.Array, cache_len: jax.Array,
-                     new_lens: jax.Array, interpret: bool = False,
+                     layer: jax.Array, page_idx: jax.Array,
+                     cache_len: jax.Array, new_lens: jax.Array,
+                     interpret: bool = False,
                      block_q: int = 0) -> jax.Array:
-    """q: (B, S, H, hd) right-aligned chunks; k/v_pages: (n_pages, ps, KVH,
-    hd); page_idx: (B, P) int32 (-1 = unused lane); cache_len: (B,) total
-    valid length AFTER the chunk; new_lens: (B,) valid trailing columns.
-    -> (B, S, H, hd) (padding columns zero)."""
-    return _chunk_attn_common(q, (k_pages, v_pages), page_idx, cache_len,
-                              new_lens, interpret, block_q)
+    """q: (B, S, H, hd) right-aligned chunks; k/v_pages: (L, n_pages, ps,
+    KVH * hd); layer: int32 scalar; page_idx: (B, P) int32 (-1 = unused
+    lane); cache_len: (B,) total valid length AFTER the chunk; new_lens:
+    (B,) valid trailing columns.  -> (B, S, H, hd) (padding columns
+    zero)."""
+    return _chunk_attn_common(q, (k_pages, v_pages), layer, page_idx,
+                              cache_len, new_lens, interpret, block_q)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_q"))
 def _chunk_attn_quant_call(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, k_scale: jax.Array,
-                           v_scale: jax.Array, page_idx: jax.Array,
-                           cache_len: jax.Array, new_lens: jax.Array,
-                           interpret: bool = False,
+                           v_scale: jax.Array, layer: jax.Array,
+                           page_idx: jax.Array, cache_len: jax.Array,
+                           new_lens: jax.Array, interpret: bool = False,
                            block_q: int = 0) -> jax.Array:
-    """Quantized-pool variant: k/v_pages are (n_pages, ps, KVH, hd) int8
-    and k/v_scale (n_pages, KVH) float32 per-page scales; both ride the
-    same scalar-prefetched page-index path and pages dequantize in VMEM
-    (``kernels.quant``).  Same shapes/masking otherwise."""
+    """Quantized-pool variant: k/v_pages are (L, n_pages, ps, KVH * hd)
+    int8 and k/v_scale (L, n_pages, KVH) float32 per-page scales; the
+    pages ride the scalar-prefetched page-index path, the layer's scales
+    sit in VMEM and pages dequantize there (``kernels.quant``).  Same
+    shapes/masking otherwise."""
     return _chunk_attn_common(q, (k_pages, v_pages, k_scale, v_scale),
-                              page_idx, cache_len, new_lens, interpret,
-                              block_q)
+                              layer, page_idx, cache_len, new_lens,
+                              interpret, block_q)

@@ -6,18 +6,20 @@ head)** — symmetric absmax quantization, the act-quant pattern of the
 DeepSeek-V3 fp8 exemplar (SNIPPETS.md snippet 3) applied at page
 granularity so the scales ride in pool metadata exactly like BRAVO keeps
 rbias/inhibit compact per lock.  Dequantization happens INSIDE the
-paged-attention kernels at DMA time (the scale block is fetched through
-the same scalar-prefetched page-index path as the page itself), so the
-lowered steps never hold a dense KV buffer or an fp32 copy of the pool.
+paged-attention kernels, in VMEM (the int8 page streams in through the
+scalar-prefetched page-index path; the layer's scales sit whole in
+VMEM), so the lowered steps never hold a dense KV buffer or an fp32 copy
+of the pool.
 
 Page byte layout (the ROADMAP standing-constraint contract):
 
-* content: ``(page_size, KVH, hd) int8`` per page per layer — exactly
-  half the bytes of the bf16 store, a quarter of fp32;
+* content: ``(page_size, KVH * hd) int8`` per page per layer, the KV
+  heads side by side on the lanes like the bf16 store — exactly half the
+  bytes of the bf16 store, a quarter of fp32;
 * scale: ``(KVH,) float32`` per page per layer, living in the page-store
   pytree beside the content (``{"k","v","k_scale","v_scale"}``) so the
-  layer scan, step donation and the engine's COW page copy treat content
-  and scale as ONE unit — a COW copy that moved the bytes but not the
+  layer scan's carry, step donation and the engine's COW page copy treat
+  content and scale as ONE unit — a COW copy that moved the bytes but not the
   scale would silently rescale the shared prefix (the
   ``cow-skips-scale`` checker mutation).
 
@@ -50,11 +52,12 @@ QUANT_EPS = 1e-6
 def quantize_pages(x: jax.Array):
     """Symmetric absmax int8 quantization over the (slot, hd) axes.
 
-    x: ``(..., page_size, KVH, hd)`` float -> ``(int8 same shape,
-    float32 scales (..., KVH))`` with ``scale = max(|x|, eps) / 127`` per
-    (page, KV head) and ``q = clip(round(x / scale), -127, 127)``.  The
-    group max always maps to exactly ±127, so a quantize -> dequantize ->
-    quantize round trip is bit-stable (same int8, same scale)."""
+    x: ``(..., page_size, KVH, hd)`` float (the store's lanes split per KV
+    head) -> ``(int8 same shape, float32 scales (..., KVH))`` with
+    ``scale = max(|x|, eps) / 127`` per (page, KV head) and ``q =
+    clip(round(x / scale), -127, 127)``.  The group max always maps to
+    exactly ±127, so a quantize -> dequantize -> quantize round trip is
+    bit-stable (same int8, same scale)."""
     xf = x.astype(jnp.float32)
     amax = jnp.max(jnp.abs(xf), axis=(-3, -1))
     scale = jnp.maximum(amax, QUANT_EPS) / 127.0
@@ -71,43 +74,49 @@ def dequantize_pages(q: jax.Array, scale: jax.Array) -> jax.Array:
 
 
 def requant_scatter(kq, vq, ks, vs, k_new, v_new, pages, cache_len,
-                    new_lens=None):
-    """Merge a step's fresh K/V into the quantized page store.
+                    new_lens=None, layer=0):
+    """Merge a step's fresh K/V into layer ``layer`` of the quantized page
+    store.
 
-    kq/vq: ``(n_pages, ps, KVH, hd) int8``; ks/vs: ``(n_pages, KVH)``
-    float32; k_new/v_new: ``(B, S, KVH, hd)`` (right-aligned chunks —
-    row i's last ``new_lens[i]`` columns are real); pages: ``(B,
-    n_lanes)`` page-index vectors; cache_len: ``(B,)`` total valid
-    length AFTER the chunk.  -> (kq', vq', ks', vs').
+    kq/vq: ``(L, n_pages, ps, KVH * hd) int8``; ks/vs: ``(L, n_pages,
+    KVH)`` float32; k_new/v_new: ``(B, S, KVH, hd)`` (right-aligned chunks
+    — row i's last ``new_lens[i]`` columns are real); pages: ``(B,
+    n_lanes)`` page-index vectors; cache_len: ``(B,)`` total valid length
+    AFTER the chunk; layer: int32 scalar.  -> (kq', vq', ks', vs').
 
     The touched window per row is the static ``n_touch`` lanes starting
     at the first lane holding a NEW token (``(cache_len - new_lens) //
     ps``) — shared prefix pages sit strictly below it and are never
     gathered, rescaled or written back, which is what keeps the COW
-    contract intact at the byte level.  Rows never share a touched page
-    (pages are request-private while written), so the scatter-back has
-    no conflicts by construction.
+    contract intact at the byte level.  Only ``layer``'s pages are
+    gathered and scattered back; every other layer's bytes stay as they
+    are.  Rows never share a touched page (pages are request-private
+    while written), so the scatter-back has no conflicts by construction.
     """
-    n_pages, ps, kvh, hd = kq.shape
-    b, s = k_new.shape[:2]
+    _, n_pages, ps, lanes = kq.shape
+    b, s, kvh, hd = k_new.shape
     n_lanes = pages.shape[1]
     nl = (new_lens if new_lens is not None
           else jnp.full((b,), s, jnp.int32))
     n_touch = min((s + ps - 2) // ps + 1, n_lanes)
 
     lo = jnp.clip((cache_len - nl) // ps, 0, n_lanes - 1)          # (B,)
-    lanes = lo[:, None] + jnp.arange(n_touch)[None, :]             # (B, T)
-    lane_ok = (lanes < n_lanes) & (lanes * ps < cache_len[:, None])
-    pg = jnp.take_along_axis(pages, jnp.clip(lanes, 0, n_lanes - 1),
+    lanes_t = lo[:, None] + jnp.arange(n_touch)[None, :]           # (B, T)
+    lane_ok = (lanes_t < n_lanes) & (lanes_t * ps < cache_len[:, None])
+    pg = jnp.take_along_axis(pages, jnp.clip(lanes_t, 0, n_lanes - 1),
                              axis=1)
     pg = jnp.where(lane_ok & (pg >= 0), pg, n_pages)       # -> drop tag
     safe = jnp.clip(pg, 0, n_pages - 1)
 
-    kbuf = dequantize_pages(kq[safe], ks[safe])      # (B, T, ps, KVH, hd)
-    vbuf = dequantize_pages(vq[safe], vs[safe])
+    def gather(x, sc):                               # (B, T, ps, KVH, hd)
+        return dequantize_pages(
+            x[layer, safe].reshape(b, n_touch, ps, kvh, hd), sc[layer, safe])
+
+    kbuf = gather(kq, ks)
+    vbuf = gather(vq, vs)
     # zero every slot at/after cache_len: stale bytes from the page's
     # previous life must not leak into the fresh scale
-    pos = lanes[:, :, None] * ps + jnp.arange(ps)[None, None, :]
+    pos = lanes_t[:, :, None] * ps + jnp.arange(ps)[None, None, :]
     keep = (pos < cache_len[:, None, None])[..., None, None]
     kbuf = jnp.where(keep, kbuf, 0.0)
     vbuf = jnp.where(keep, vbuf, 0.0)
@@ -125,10 +134,11 @@ def requant_scatter(kq, vq, ks, vs, k_new, v_new, pages, cache_len,
 
     kq2, ks2 = quantize_pages(kbuf)
     vq2, vs2 = quantize_pages(vbuf)
-    return (kq.at[pg].set(kq2, mode="drop"),
-            vq.at[pg].set(vq2, mode="drop"),
-            ks.at[pg].set(ks2, mode="drop"),
-            vs.at[pg].set(vs2, mode="drop"))
+    dense = (b, n_touch, ps, lanes)
+    return (kq.at[layer, pg].set(kq2.reshape(dense), mode="drop"),
+            vq.at[layer, pg].set(vq2.reshape(dense), mode="drop"),
+            ks.at[layer, pg].set(ks2, mode="drop"),
+            vs.at[layer, pg].set(vs2, mode="drop"))
 
 
 def quant_layout_tag(page_size: int, kvh: int, hd: int) -> int:

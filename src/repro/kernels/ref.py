@@ -8,6 +8,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from .paged_attn import NT, gather_out, head_scales, spread_q
+
 
 def scan_ref(table2d: jax.Array, lock_id) -> tuple[jax.Array, jax.Array]:
     """-> (mask int8 (rows,128), count int32 scalar)."""
@@ -77,231 +79,208 @@ def multi_count_ref(table2d: jax.Array, lock_ids: jax.Array) -> jax.Array:
                    .astype(jnp.int32), axis=0)
 
 
-def paged_attn_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                   page_idx: jax.Array, cache_len: jax.Array) -> jax.Array:
-    """Oracle for the gather-by-page decode attention kernel.
+def one_layer(x: jax.Array) -> jax.Array:
+    """Pages ``(n_pages, ps, KVH, hd)`` -> a one-layer store leaf ``(1,
+    n_pages, ps, KVH * hd)`` in the store's lane-dense layout; per-page
+    scales ``(n_pages, KVH)`` -> ``(1, n_pages, KVH)``."""
+    if x.ndim == 4:
+        n, ps, kvh, hd = x.shape
+        return x.reshape(1, n, ps, kvh * hd)
+    return x[None]
 
-    Walks the page-index vector in the SAME order as the kernel's grid
-    (online softmax, one page per step, identical per-request einsums) so
-    interpret-mode runs can be compared bit for bit, not just allclose —
-    run the oracle under ``jax.jit`` for the comparison, so both sides get
-    the same XLA fusion (FMA contraction) of the accumulator update.
-    q: (B, H, hd); k/v_pages: (n_pages, ps, KVH, hd); page_idx: (B, P)
-    int32 (-1 = unused); cache_len: (B,).  -> (B, H, hd).
-    """
+
+def _decode_walk(q, store, layer, page_idx, cache_len):
+    """The decode kernel's page walk, op for op, as the kernel's grid runs
+    it (a loop over rows, in it a loop over pages): ``store`` is (k_pages,
+    v_pages) or, for the int8 pool, (k_pages, v_pages, k_scale,
+    v_scale)."""
     b, h, hd = q.shape
-    _, ps, kvh, _ = k_pages.shape
+    _, _, ps, lanes = store[0].shape
+    kvh = lanes // hd
     n_p = page_idx.shape[1]
-    g = h // kvh
     scale = 1.0 / math.sqrt(hd)
-    outs = []
-    for bi in range(b):       # per request, exactly one grid row's ops
-        qh = q[bi].astype(jnp.float32).reshape(kvh, g, hd)
-        m = jnp.full((h, 1), -jnp.inf, jnp.float32)
-        den = jnp.zeros((h, 1), jnp.float32)
-        acc = jnp.zeros((h, hd), jnp.float32)
-        for p in range(n_p):
+    quantized = len(store) == 4
+
+    def row(bi):
+        qbd = spread_q(q[bi].astype(jnp.float32), kvh)
+
+        def step(p, carry):
+            m, den, acc = carry
             page = page_idx[bi, p]
-            k = k_pages[jnp.clip(page, 0)].astype(jnp.float32)
-            v = v_pages[jnp.clip(page, 0)].astype(jnp.float32)
+            i = jnp.maximum(page, 0)
+            k = store[0][layer, i].astype(jnp.float32)     # (ps, KVH * hd)
+            v = store[1][layer, i].astype(jnp.float32)
             pos = p * ps + jnp.arange(ps)[None, :]
             valid = (pos < cache_len[bi]) & (page >= 0)        # (1, ps)
-            s = jnp.einsum("kgd,skd->kgs", qh, k,
-                           preferred_element_type=jnp.float32) * scale
-            s = jnp.where(valid, s.reshape(h, ps), -jnp.inf)
+            s = jax.lax.dot_general(qbd, k, NT,
+                                    preferred_element_type=jnp.float32)
+            if quantized:
+                s = s * head_scales(store[2][layer, i][None, :], h)
+            s = jnp.where(valid, s * scale, -jnp.inf)
             m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
             m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
             pexp = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
             corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
             den = den * corr + jnp.sum(pexp, axis=1, keepdims=True)
-            pv = jnp.einsum("kgs,skd->kgd", pexp.reshape(kvh, g, ps), v,
-                            preferred_element_type=jnp.float32)
-            acc = acc * corr + pv.reshape(h, hd)
-            m = m_new
-        outs.append(acc / jnp.maximum(den, 1e-20))
-    return jnp.stack(outs).astype(q.dtype)
+            pv = jnp.dot(pexp, v, preferred_element_type=jnp.float32)
+            if quantized:
+                pv = pv * head_scales(store[3][layer, i][None, :], h)
+            return m_new, den, acc * corr + pv
+
+        m, den, acc = jax.lax.fori_loop(
+            0, n_p, step, (jnp.full((h, 1), -jnp.inf, jnp.float32),
+                           jnp.zeros((h, 1), jnp.float32),
+                           jnp.zeros((h, lanes), jnp.float32)))
+        return gather_out(acc / jnp.maximum(den, 1e-20), hd)
+
+    return jax.lax.map(row, jnp.arange(b)).astype(q.dtype)
 
 
-def paged_chunk_attn_ref(q: jax.Array, k_pages: jax.Array,
-                         v_pages: jax.Array, page_idx: jax.Array,
-                         cache_len: jax.Array, new_lens: jax.Array,
-                         block_q: int = 0) -> jax.Array:
-    """Oracle for the streaming chunk-prefill attention kernel.
+def paged_attn_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
+                   layer, page_idx: jax.Array,
+                   cache_len: jax.Array) -> jax.Array:
+    """Oracle for the gather-by-page decode attention kernel.
 
-    Walks (row, q-block, page) in the SAME order as the kernel's grid
-    (online softmax, one page per inner step, identical per-block einsums)
-    so interpret-mode runs can be compared bit for bit — run the oracle
-    under ``jax.jit`` for the comparison, like :func:`paged_attn_ref`.
-    q: (B, S, H, hd) right-aligned chunks; k/v_pages: (n_pages, ps, KVH,
-    hd); page_idx: (B, P) int32 (-1 = unused); cache_len: (B,) total valid
-    length AFTER the chunk; new_lens: (B,) valid trailing columns.
-    -> (B, S, H, hd) (padding columns zero).
+    Walks the page-index vector in the SAME order as the kernel's grid
+    (online softmax, one page per step, identical per-request matmuls) so
+    interpret-mode runs can be compared bit for bit, not just allclose —
+    run the oracle under ``jax.jit`` for the comparison, so both sides get
+    the same XLA fusion (FMA contraction) of the accumulator update.
+    q: (B, H, hd); k/v_pages: (L, n_pages, ps, KVH * hd); layer: int32
+    scalar; page_idx: (B, P) int32 (-1 = unused); cache_len: (B,).
+    -> (B, H, hd).
     """
-    from .paged_chunk_attn import _pick_block_q
-
-    b, s, h, hd = q.shape
-    _, ps, kvh, _ = k_pages.shape
-    n_p = page_idx.shape[1]
-    g = h // kvh
-    scale = 1.0 / math.sqrt(hd)
-    bq = block_q or _pick_block_q(s)
-    assert s % bq == 0, (s, bq)      # same contract as the kernel call
-    outs = []
-    for bi in range(b):
-        rows = []
-        for qi in range(s // bq):
-            col = qi * bq + jnp.arange(bq)[:, None]            # (bq, 1)
-            q_pos = cache_len[bi] - s + col
-            valid_q = (col >= s - new_lens[bi]) & (q_pos >= 0)
-            # head-major block, heads grouped by kv head: (KVH, g*bq, hd)
-            qh = q[bi, qi * bq:(qi + 1) * bq].astype(jnp.float32) \
-                .transpose(1, 0, 2).reshape(kvh, g * bq, hd)
-            m = jnp.full((kvh, g * bq, 1), -jnp.inf, jnp.float32)
-            den = jnp.zeros((kvh, g * bq, 1), jnp.float32)
-            acc = jnp.zeros((kvh, g * bq, hd), jnp.float32)
-            for p in range(n_p):
-                page = page_idx[bi, p]
-                k = k_pages[jnp.clip(page, 0)].astype(jnp.float32)
-                v = v_pages[jnp.clip(page, 0)].astype(jnp.float32)
-                t_pos = p * ps + jnp.arange(ps)[None, :]       # (1, ps)
-                valid = (t_pos < cache_len[bi]) & (page >= 0) \
-                    & (t_pos <= q_pos) & valid_q
-                valid = jnp.concatenate([valid] * g, axis=0)[None]
-                sc = jnp.einsum("kqd,skd->kqs", qh, k,
-                                preferred_element_type=jnp.float32) * scale
-                sc = jnp.where(valid, sc, -jnp.inf)
-                m_new = jnp.maximum(m, jnp.max(sc, axis=2, keepdims=True))
-                m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-                pexp = jnp.where(valid, jnp.exp(sc - m_safe), 0.0)
-                corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
-                den = den * corr + jnp.sum(pexp, axis=2, keepdims=True)
-                pv = jnp.einsum("kqs,skd->kqd", pexp, v,
-                                preferred_element_type=jnp.float32)
-                acc = acc * corr + pv
-                m = m_new
-            out = acc / jnp.maximum(den, 1e-20)
-            rows.append(out.reshape(h, bq, hd).transpose(1, 0, 2))
-        outs.append(jnp.concatenate(rows, axis=0))
-    return jnp.stack(outs).astype(q.dtype)
+    return _decode_walk(q, (k_pages, v_pages), layer, page_idx, cache_len)
 
 
 def paged_attn_quant_ref(q: jax.Array, k_pages: jax.Array,
                          v_pages: jax.Array, k_scale: jax.Array,
-                         v_scale: jax.Array, page_idx: jax.Array,
+                         v_scale: jax.Array, layer, page_idx: jax.Array,
                          cache_len: jax.Array) -> jax.Array:
-    """Oracle for the quantized decode kernel: identical page walk to
-    :func:`paged_attn_ref`, with the kernel's exact dequant op order
-    (int8 ``astype`` then one broadcast scale multiply per page) so
-    interpret-mode runs compare bit for bit.  k/v_pages int8, k/v_scale
-    (n_pages, KVH) float32."""
+    """Oracle for the quantized decode kernel: the page walk of
+    :func:`paged_attn_ref` with the kernel's exact scale op order (the
+    int8 matmuls, then each query head's KV-head scale on the scores and
+    on ``p @ v``), so interpret-mode runs compare bit for bit.  k/v_pages
+    int8, k/v_scale (L, n_pages, KVH) float32."""
+    return _decode_walk(q, (k_pages, v_pages, k_scale, v_scale), layer,
+                        page_idx, cache_len)
 
-    def deq(pages, scales, page):
-        i = jnp.clip(page, 0)
-        return pages[i].astype(jnp.float32) * scales[i][None, :, None]
 
-    b, h, hd = q.shape
-    _, ps, kvh, _ = k_pages.shape
+def _chunk_walk(q, store, layer, page_idx, cache_len, new_lens, block_q):
+    """The chunk kernel's walk, op for op, as its grid runs it (loops over
+    rows, q-blocks and pages; in each page, one KV head after the other):
+    ``store`` is (k_pages, v_pages) or (k_pages, v_pages, k_scale,
+    v_scale)."""
+    from .paged_chunk_attn import _pick_block_q
+
+    b, s, h, hd = q.shape
+    _, _, ps, lanes = store[0].shape
+    kvh = lanes // hd
     n_p = page_idx.shape[1]
     g = h // kvh
     scale = 1.0 / math.sqrt(hd)
-    outs = []
-    for bi in range(b):
-        qh = q[bi].astype(jnp.float32).reshape(kvh, g, hd)
-        m = jnp.full((h, 1), -jnp.inf, jnp.float32)
-        den = jnp.zeros((h, 1), jnp.float32)
-        acc = jnp.zeros((h, hd), jnp.float32)
-        for p in range(n_p):
+    quantized = len(store) == 4
+    bq = block_q or _pick_block_q(s)
+    assert s % bq == 0, (s, bq)      # same contract as the kernel call
+
+    def block(bi, qi):
+        col = qi * bq + jnp.arange(bq)[:, None]                # (bq, 1)
+        q_pos = cache_len[bi] - s + col
+        valid_q = (col >= s - new_lens[bi]) & (q_pos >= 0)
+        # the head-major block: (H, bq, hd)
+        qb = jax.lax.dynamic_slice_in_dim(q[bi], qi * bq, bq).transpose(
+            1, 0, 2)
+
+        def step(p, carry):
+            m, den, acc = carry
             page = page_idx[bi, p]
-            k = deq(k_pages, k_scale, page)
-            v = deq(v_pages, v_scale, page)
-            pos = p * ps + jnp.arange(ps)[None, :]
-            valid = (pos < cache_len[bi]) & (page >= 0)
-            s = jnp.einsum("kgd,skd->kgs", qh, k,
-                           preferred_element_type=jnp.float32) * scale
-            s = jnp.where(valid, s.reshape(h, ps), -jnp.inf)
-            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-            m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-            pexp = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
-            corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
-            den = den * corr + jnp.sum(pexp, axis=1, keepdims=True)
-            pv = jnp.einsum("kgs,skd->kgd", pexp.reshape(kvh, g, ps), v,
-                            preferred_element_type=jnp.float32)
-            acc = acc * corr + pv.reshape(h, hd)
-            m = m_new
-        outs.append(acc / jnp.maximum(den, 1e-20))
-    return jnp.stack(outs).astype(q.dtype)
+            i = jnp.maximum(page, 0)
+            t_pos = p * ps + jnp.arange(ps)[None, :]           # (1, ps)
+            valid = (t_pos < cache_len[bi]) & (page >= 0) \
+                & (t_pos <= q_pos) & valid_q
+            valid = jnp.concatenate([valid] * g, axis=0)
+            for c in range(kvh):
+                sl = slice(c * hd, (c + 1) * hd)
+                qh = qb[c * g:(c + 1) * g].astype(jnp.float32).reshape(
+                    g * bq, hd)
+                k = store[0][layer, i][:, sl].astype(jnp.float32)
+                v = store[1][layer, i][:, sl].astype(jnp.float32)
+                if quantized:
+                    k = k * store[2][layer, i, c]
+                    v = v * store[3][layer, i, c]
+                sc = jax.lax.dot_general(
+                    qh, k, NT, preferred_element_type=jnp.float32) * scale
+                sc = jnp.where(valid, sc, -jnp.inf)
+                m_new = jnp.maximum(m[c], jnp.max(sc, axis=1, keepdims=True))
+                m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+                pexp = jnp.where(valid, jnp.exp(sc - m_safe), 0.0)
+                corr = jnp.where(jnp.isfinite(m[c]),
+                                 jnp.exp(m[c] - m_safe), 0.0)
+                den = den.at[c].set(den[c] * corr
+                                    + jnp.sum(pexp, axis=1, keepdims=True))
+                acc = acc.at[c].set(acc[c] * corr + jnp.dot(
+                    pexp, v, preferred_element_type=jnp.float32))
+                m = m.at[c].set(m_new)
+            return m, den, acc
+
+        m, den, acc = jax.lax.fori_loop(
+            0, n_p, step,
+            (jnp.full((kvh, g * bq, 1), -jnp.inf, jnp.float32),
+             jnp.zeros((kvh, g * bq, 1), jnp.float32),
+             jnp.zeros((kvh, g * bq, hd), jnp.float32)))
+        out = acc / jnp.maximum(den, 1e-20)
+        return out.reshape(h, bq, hd).transpose(1, 0, 2)       # (bq, H, hd)
+
+    out = jax.lax.map(lambda bi: jax.lax.map(
+        lambda qi: block(bi, qi), jnp.arange(s // bq)), jnp.arange(b))
+    return out.reshape(b, s, h, hd).astype(q.dtype)
+
+
+def paged_chunk_attn_ref(q: jax.Array, k_pages: jax.Array,
+                         v_pages: jax.Array, layer, page_idx: jax.Array,
+                         cache_len: jax.Array, new_lens: jax.Array,
+                         block_q: int = 0) -> jax.Array:
+    """Oracle for the streaming chunk-prefill attention kernel.
+
+    Walks (row, q-block, page, KV head) in the SAME order as the kernel
+    (online softmax, one page per inner step, identical per-KV-head
+    matmuls) so interpret-mode runs can be compared bit for bit — run the
+    oracle under ``jax.jit`` for the comparison, like
+    :func:`paged_attn_ref`.  q: (B, S, H, hd) right-aligned chunks;
+    k/v_pages: (L, n_pages, ps, KVH * hd); layer: int32 scalar; page_idx:
+    (B, P) int32 (-1 = unused); cache_len: (B,) total valid length AFTER
+    the chunk; new_lens: (B,) valid trailing columns.  -> (B, S, H, hd)
+    (padding columns zero).
+    """
+    return _chunk_walk(q, (k_pages, v_pages), layer, page_idx, cache_len,
+                       new_lens, block_q)
 
 
 def paged_chunk_attn_quant_ref(q: jax.Array, k_pages: jax.Array,
                                v_pages: jax.Array, k_scale: jax.Array,
-                               v_scale: jax.Array, page_idx: jax.Array,
-                               cache_len: jax.Array, new_lens: jax.Array,
+                               v_scale: jax.Array, layer,
+                               page_idx: jax.Array, cache_len: jax.Array,
+                               new_lens: jax.Array,
                                block_q: int = 0) -> jax.Array:
-    """Oracle for the quantized chunk-prefill kernel: identical (row,
-    q-block, page) walk to :func:`paged_chunk_attn_ref` with the kernel's
-    exact dequant op order."""
-    from .paged_chunk_attn import _pick_block_q
-
-    def deq(pages, scales, page):
-        i = jnp.clip(page, 0)
-        return pages[i].astype(jnp.float32) * scales[i][None, :, None]
-
-    b, s, h, hd = q.shape
-    _, ps, kvh, _ = k_pages.shape
-    n_p = page_idx.shape[1]
-    g = h // kvh
-    scale = 1.0 / math.sqrt(hd)
-    bq = block_q or _pick_block_q(s)
-    assert s % bq == 0, (s, bq)
-    outs = []
-    for bi in range(b):
-        rows = []
-        for qi in range(s // bq):
-            col = qi * bq + jnp.arange(bq)[:, None]            # (bq, 1)
-            q_pos = cache_len[bi] - s + col
-            valid_q = (col >= s - new_lens[bi]) & (q_pos >= 0)
-            # head-major block, heads grouped by kv head: (KVH, g*bq, hd)
-            qh = q[bi, qi * bq:(qi + 1) * bq].astype(jnp.float32) \
-                .transpose(1, 0, 2).reshape(kvh, g * bq, hd)
-            m = jnp.full((kvh, g * bq, 1), -jnp.inf, jnp.float32)
-            den = jnp.zeros((kvh, g * bq, 1), jnp.float32)
-            acc = jnp.zeros((kvh, g * bq, hd), jnp.float32)
-            for p in range(n_p):
-                page = page_idx[bi, p]
-                k = deq(k_pages, k_scale, page)
-                v = deq(v_pages, v_scale, page)
-                t_pos = p * ps + jnp.arange(ps)[None, :]       # (1, ps)
-                valid = (t_pos < cache_len[bi]) & (page >= 0) \
-                    & (t_pos <= q_pos) & valid_q
-                valid = jnp.concatenate([valid] * g, axis=0)[None]
-                sc = jnp.einsum("kqd,skd->kqs", qh, k,
-                                preferred_element_type=jnp.float32) * scale
-                sc = jnp.where(valid, sc, -jnp.inf)
-                m_new = jnp.maximum(m, jnp.max(sc, axis=2, keepdims=True))
-                m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-                pexp = jnp.where(valid, jnp.exp(sc - m_safe), 0.0)
-                corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
-                den = den * corr + jnp.sum(pexp, axis=2, keepdims=True)
-                pv = jnp.einsum("kqs,skd->kqd", pexp, v,
-                                preferred_element_type=jnp.float32)
-                acc = acc * corr + pv
-                m = m_new
-            out = acc / jnp.maximum(den, 1e-20)
-            rows.append(out.reshape(h, bq, hd).transpose(1, 0, 2))
-        outs.append(jnp.concatenate(rows, axis=0))
-    return jnp.stack(outs).astype(q.dtype)
+    """Oracle for the quantized chunk-prefill kernel: the walk of
+    :func:`paged_chunk_attn_ref` with the kernel's exact dequant op order
+    (a KV head's int8 lanes cast, then times its page scale)."""
+    return _chunk_walk(q, (k_pages, v_pages, k_scale, v_scale), layer,
+                       page_idx, cache_len, new_lens, block_q)
 
 
 def paged_chunk_dense_ref(q: jax.Array, k_pages: jax.Array,
-                          v_pages: jax.Array, page_idx: jax.Array,
+                          v_pages: jax.Array, layer, page_idx: jax.Array,
                           cache_len: jax.Array,
                           new_lens: jax.Array) -> jax.Array:
-    """The PR-4 dense chunk-attention path (gather every page into a
-    contiguous ``(B, lanes * ps, KVH, hd)`` buffer, one full softmax):
-    kept as the allclose cross-check and the benchmark's dense baseline —
-    this materialization is exactly what the streaming kernel avoids."""
+    """The PR-4 dense chunk-attention path (gather every page of ``layer``
+    into a contiguous ``(B, lanes * ps, KVH, hd)`` buffer, one full
+    softmax): kept as the allclose cross-check and the benchmark's dense
+    baseline — this materialization is exactly what the streaming kernel
+    avoids."""
     b, s, h, hd = q.shape
-    n_pages, ps, kvh, _ = k_pages.shape
+    _, _, ps, lanes = k_pages.shape
+    kvh = lanes // hd
     n_lanes = page_idx.shape[1]
     g = h // kvh
     scale = 1.0 / math.sqrt(hd)
@@ -309,8 +288,10 @@ def paged_chunk_dense_ref(q: jax.Array, k_pages: jax.Array,
     valid_q = (jnp.arange(s)[None, :] >= s - new_lens[:, None]) \
         & (q_pos >= 0)
     safe = jnp.clip(page_idx, 0)
-    kd = k_pages[safe].reshape(b, n_lanes * ps, kvh, hd).astype(jnp.float32)
-    vd = v_pages[safe].reshape(b, n_lanes * ps, kvh, hd).astype(jnp.float32)
+    kd = k_pages[layer][safe].reshape(b, n_lanes * ps, kvh, hd).astype(
+        jnp.float32)
+    vd = v_pages[layer][safe].reshape(b, n_lanes * ps, kvh, hd).astype(
+        jnp.float32)
     t = jnp.arange(n_lanes * ps)
     valid_t = (t[None, :] < cache_len[:, None]) \
         & jnp.repeat(page_idx >= 0, ps, axis=1)                   # (B, T)

@@ -138,11 +138,14 @@ def dense_stack(p: Params, x: jax.Array, cfg: ModelConfig, *, positions,
     """Dense / audio / vlm transformer stack (scan over L layers).
 
     ``pages``/``new_lens`` (paged serving): caches are the pool's page
-    store with a leading layer dim, scanned like dense caches; the (B, P)
-    page-index matrix is closed over (shared by every layer)."""
+    store with a leading layer dim.  The scan CARRIES the whole store and
+    scans the layer index beside the weights: each layer writes its K/V
+    into the carried store in place and hands the kernels the store and
+    its index, so no layer's slice is cut out, relaid out or stacked back.
+    The (B, P) page-index matrix is closed over (shared by every layer)."""
     aux_total = jnp.zeros((), jnp.float32)
 
-    def body(x, lp, lc):
+    def body(x, lp, lc, layer=None):
         if lc is None:   # keep FSDP storage sharding (see dist.sharding)
             lp = constrain_layer_params(lp, rules, mesh)
 
@@ -153,7 +156,7 @@ def dense_stack(p: Params, x: jax.Array, cfg: ModelConfig, *, positions,
                 cache=lc, cache_len=cache_len,
                 attn_seqshard=(rules.attn_impl == "seqshard"),
                 keep_seq_sharded=rules.residual_seq,
-                pages=pages, new_lens=new_lens)
+                pages=pages, new_lens=new_lens, layer=layer)
         if remat_policy is not None and lc is None:
             blk = jax.checkpoint(blk, policy=remat_policy)
         y, _, nc = blk(x)
@@ -161,6 +164,15 @@ def dense_stack(p: Params, x: jax.Array, cfg: ModelConfig, *, positions,
         return y, nc
 
     stacked = {"attn": p["layers"]["attn"], "mlp": p["layers"]["mlp"]}
+
+    if pages is not None:
+        def paged(carry, inp):
+            (x, store), (lp, layer) = carry, inp
+            return body(x, lp, store, layer), None
+
+        (x, store), _ = lax.scan(paged, (x, caches),
+                                 (stacked, jnp.arange(cfg.n_layers)))
+        return x, aux_total, store
 
     def f(carry, inp):
         lp, lc = inp
@@ -418,20 +430,26 @@ def init_paged_caches(cfg: ModelConfig, n_pages: int, page_size: int,
                       dtype=jnp.bfloat16, quantized: bool = False) -> Any:
     """Zero-initialized page STORE for the paged decode path: one pool of
     ``n_pages`` KV pages shared by every request, with a leading layer dim
-    scanned like the dense caches.  The (request -> pages) map lives in
-    ``serving.kv_pool.KVPool``; requests address the store through their
-    (B, P) page-index vectors.
+    that the layer scan carries whole.  The (request -> pages) map lives
+    in ``serving.kv_pool.KVPool``; requests address the store through
+    their (B, P) page-index vectors.
+
+    A page is lane-dense, ``(page_size, KVH * hd)``: the KV heads sit side
+    by side on the lanes, KV head ``j`` on ``[j * hd, (j + 1) * hd)``.
+    That row-major layout has no tile padding, so it is the layout the
+    store has at rest, the one the kernels read and the one each step
+    writes in place: XLA never relays the store out.
 
     ``quantized=True`` stores pages int8 with float32 per-(page, KV head)
     scales (``kernels.quant`` layout) as sibling leaves ``k_scale`` /
-    ``v_scale`` of shape (n_layers, n_pages, KVH): the layer scan slices
+    ``v_scale`` of shape (n_layers, n_pages, KVH): the layer scan carries
     them alongside the content, step donation covers them, and the
     engine's COW page copy moves content + scale as one unit."""
     if cfg.family not in ("dense", "vlm"):
         raise ValueError(f"paged caches need dense attention "
                          f"(family={cfg.family})")
     kvh, hd = cfg.n_kv_heads, cfg.hd
-    shape = (cfg.n_layers, n_pages, page_size, kvh, hd)
+    shape = (cfg.n_layers, n_pages, page_size, kvh * hd)
     if quantized:
         return {"k": jnp.zeros(shape, jnp.int8),
                 "v": jnp.zeros(shape, jnp.int8),

@@ -79,31 +79,35 @@ def init_moe(key, cfg: ModelConfig, n: int) -> Params:
 # ---------------------------------------------------------------------------
 
 
-def _paged_kernel_sharded(kernel, q, store, rows, mesh, data_axes):
-    """Run a paged-attention Pallas kernel ``kernel(q, *store, *rows)``
-    on a live mesh.
+def _paged_kernel_sharded(kernel, q, store, layer, rows, mesh, data_axes):
+    """Run a paged-attention Pallas kernel ``kernel(q, *store, layer,
+    *rows)`` on a live mesh.
 
     A ``pallas_call`` is opaque to the SPMD partitioner, so the layout is
     explicit, through ``shard_map_compat`` (the tree's one ``jax.shard_map``
     call site, which the source lint enforces): requests split over the
     data axes when those divide the batch, and query heads over ``model``.
-    The page store splits its KV heads over ``model`` when they divide
-    evenly; a single KV head (MQA) replicates to every head shard.  Each
-    shard then streams only ITS requests' pages for ITS heads.
+    The page store splits its lane dim over ``model`` when the KV heads
+    divide evenly (a KV head's lanes are contiguous, so each shard holds
+    whole KV heads, the ones its query heads read); a single KV head (MQA)
+    replicates to every head shard.  Each shard then streams only ITS
+    requests' pages for ITS heads.
 
-    ``q`` carries its heads at axis -2; ``store`` holds the page-store
-    leaves (pages ``(n_pages, ps, KVH, hd)``, per-page scales ``(n_pages,
-    KVH)``); ``rows`` are per-request operands with the batch leading."""
+    ``q`` carries its heads at axis -2; ``store`` holds the whole page
+    store's leaves (pages ``(L, n_pages, ps, KVH * hd)``, per-page scales
+    ``(L, n_pages, KVH)``); ``layer`` is the int32 layer index; ``rows``
+    are per-request operands with the batch leading."""
     if mesh is None or getattr(mesh, "empty", False):
-        return kernel(q, *store, *rows)
-    b, h, kvh = q.shape[0], q.shape[-2], store[0].shape[2]
+        return kernel(q, *store, layer, *rows)
+    b, h = q.shape[0], q.shape[-2]
+    kvh = store[0].shape[-1] // q.shape[-1]
     bax = tuple(a for a in data_axes if a in mesh.axis_names)
     nb = math.prod(mesh.shape[a] for a in bax)
     nm = mesh.shape["model"] if "model" in mesh.axis_names else 1
     if b % nb:
         bax, nb = (), 1
     if nb == 1 and nm == 1:
-        return kernel(q, *store, *rows)
+        return kernel(q, *store, layer, *rows)
     if h % nm or (kvh % nm and kvh != 1):
         raise ValueError(
             f"paged attention splits heads over model={nm}: needs the "
@@ -112,12 +116,13 @@ def _paged_kernel_sharded(kernel, q, store, rows, mesh, data_axes):
     heads = "model" if nm > 1 else None
     kv_heads = kv_head_axis(kvh, mesh)
     q_spec = P(bax or None, *([None] * (q.ndim - 3)), heads, None)
-    store_specs = tuple(P(None, None, kv_heads, None) if x.ndim == 4
-                        else P(None, kv_heads) for x in store)
+    store_specs = tuple(P(None, None, None, kv_heads) if x.ndim == 4
+                        else P(None, None, kv_heads) for x in store)
     return shard_map_compat(
         kernel, mesh=mesh,
-        in_specs=(q_spec,) + store_specs + (P(bax or None),) * len(rows),
-        out_specs=q_spec, check_vma=False)(q, *store, *rows)
+        in_specs=(q_spec,) + store_specs + (P(),)
+        + (P(bax or None),) * len(rows),
+        out_specs=q_spec, check_vma=False)(q, *store, layer, *rows)
 
 
 def attn_forward(p: Params, x: jax.Array, cfg: ModelConfig, *,
@@ -128,21 +133,24 @@ def attn_forward(p: Params, x: jax.Array, cfg: ModelConfig, *,
                  seqshard: bool = False, keep_seq_sharded: bool = False,
                  pages: Optional[jax.Array] = None,
                  new_lens: Optional[jax.Array] = None,
+                 layer: Optional[jax.Array] = None,
                  ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """x: (B, S, d).  If ``cache`` is given (decode), S == 1 and the new K/V
     are written at position ``cache_len``; returns the updated cache.
 
-    Paged mode (``pages`` given): ``cache`` is the KV pool's page store
-    ``{"k"/"v": (n_pages, page_size, KVH, hd)}`` shared by every request;
-    ``pages`` is each request's (B, P) page-index vector and position ``t``
-    lives at ``pages[b, t // page_size]`` offset ``t % page_size``.  The
-    chunk's K/V are scattered into the pages in place and attention reads
-    by page index — S == 1 through the streaming decode kernel, S > 1
-    (chunked prefill, right-aligned with ``new_lens`` valid trailing
-    tokens per row) through the streaming chunk-prefill kernel.  A store that
-    also carries ``k_scale``/``v_scale`` leaves is the QUANTIZED pool
-    (int8 pages + per-(page, KV head) float32 scales, ``kernels.quant``):
-    writes go through ``requant_scatter`` and attention through the
+    Paged mode (``pages`` given): ``cache`` is the KV pool's WHOLE page
+    store ``{"k"/"v": (L, n_pages, page_size, KVH * hd)}`` shared by every
+    request and ``layer`` this layer's index into it; ``pages`` is each
+    request's (B, P) page-index vector and position ``t`` lives at
+    ``pages[b, t // page_size]`` offset ``t % page_size``.  The chunk's
+    K/V are scattered into the store in place (its lane-dense rows, at
+    ``[layer, page, offset]``) and attention reads by page index — S == 1
+    through the streaming decode kernel, S > 1 (chunked prefill,
+    right-aligned with ``new_lens`` valid trailing tokens per row) through
+    the streaming chunk-prefill kernel.  A store that also carries
+    ``k_scale``/``v_scale`` leaves is the QUANTIZED pool (int8 pages +
+    per-(page, KV head) float32 scales, ``kernels.quant``): writes go
+    through ``requant_scatter`` and attention through the
     in-kernel-dequant kernel variants."""
     B, S, d = x.shape
     h = rms_norm(x, p["ln"], cfg.norm_eps)
@@ -162,23 +170,23 @@ def attn_forward(p: Params, x: jax.Array, cfg: ModelConfig, *,
         from ..kernels.quant import requant_scatter
         kc, vc, ksc, vsc = requant_scatter(
             cache["k"], cache["v"], cache["k_scale"], cache["v_scale"],
-            k, v, pages, cache_len, new_lens)
+            k, v, pages, cache_len, new_lens, layer)
         if S == 1 and new_lens is None:
             o = _paged_kernel_sharded(
-                K.paged_attention_quant, q[:, 0], (kc, vc, ksc, vsc),
+                K.paged_attention_quant, q[:, 0], (kc, vc, ksc, vsc), layer,
                 (pages, cache_len), mesh, data_axes)[:, None]
         else:
             nl = new_lens if new_lens is not None \
                 else jnp.full((B,), S, jnp.int32)
             o = _paged_kernel_sharded(
-                K.paged_chunk_attention_quant, q, (kc, vc, ksc, vsc),
+                K.paged_chunk_attention_quant, q, (kc, vc, ksc, vsc), layer,
                 (pages, cache_len, nl), mesh, data_axes)
         new_cache = {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc}
     elif pages is not None:
         # paged data plane: scatter the chunk's K/V into the shared page
         # store, then attend by page index — the dense (B, S, KVH, hd)
         # cache never materializes on the decode path
-        n_pages, ps = cache["k"].shape[0], cache["k"].shape[1]
+        n_pages, ps = cache["k"].shape[1], cache["k"].shape[2]
         n_lanes = pages.shape[1]
         t_new = cache_len[:, None] - S + jnp.arange(S)[None, :]     # (B, S)
         valid_new = t_new >= 0
@@ -188,20 +196,21 @@ def attn_forward(p: Params, x: jax.Array, cfg: ModelConfig, *,
         page = jnp.take_along_axis(pages, col // ps, axis=1)        # (B, S)
         page = jnp.where(valid_new & (page >= 0), page, n_pages)    # -> drop
         off = col % ps
-        kc = cache["k"].at[page, off].set(k.astype(cache["k"].dtype),
-                                          mode="drop")
-        vc = cache["v"].at[page, off].set(v.astype(cache["v"].dtype),
-                                          mode="drop")
+        lanes = cfg.n_kv_heads * cfg.hd
+        kc = cache["k"].at[layer, page, off].set(
+            k.reshape(B, S, lanes).astype(cache["k"].dtype), mode="drop")
+        vc = cache["v"].at[layer, page, off].set(
+            v.reshape(B, S, lanes).astype(cache["v"].dtype), mode="drop")
         if S == 1 and new_lens is None:
             o = _paged_kernel_sharded(
-                K.paged_attention, q[:, 0], (kc, vc), (pages, cache_len),
-                mesh, data_axes)[:, None]
+                K.paged_attention, q[:, 0], (kc, vc), layer,
+                (pages, cache_len), mesh, data_axes)[:, None]
         else:
             nl = new_lens if new_lens is not None \
                 else jnp.full((B,), S, jnp.int32)
             o = _paged_kernel_sharded(
-                K.paged_chunk_attention, q, (kc, vc), (pages, cache_len, nl),
-                mesh, data_axes)
+                K.paged_chunk_attention, q, (kc, vc), layer,
+                (pages, cache_len, nl), mesh, data_axes)
         new_cache = {"k": kc, "v": vc}
     elif cache is None:
         if seqshard and mesh is not None:
@@ -469,13 +478,13 @@ def block_forward(p: Params, x: jax.Array, cfg: ModelConfig, *,
                   moe_weight_resident: bool = False,
                   attn_seqshard: bool = False,
                   keep_seq_sharded: bool = False,
-                  pages=None, new_lens=None):
+                  pages=None, new_lens=None, layer=None):
     a, new_cache = attn_forward(p["attn"], x, cfg, positions=positions,
                                 cache=cache, cache_len=cache_len,
                                 mesh=mesh, data_axes=tuple(data_axes or ()),
                                 seqshard=attn_seqshard,
                                 keep_seq_sharded=keep_seq_sharded,
-                                pages=pages, new_lens=new_lens)
+                                pages=pages, new_lens=new_lens, layer=layer)
     x = x + a
     if is_moe:
         m, aux = moe_forward(p["moe"], x, cfg, mesh, data_axes,

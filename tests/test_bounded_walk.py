@@ -1,11 +1,15 @@
-"""The paged kernels walk only the lanes a row holds.  The decode kernel
+"""The paged kernels walk only the lanes a row holds, and only the layer
+they are given.  The decode kernel
 runs a row's ``ceil(cache_len / page_size)`` live lanes; the chunk kernel
 runs, per (row, q-block), the lanes up to the block's causal end, and none
 for padding rows or blocks of padding columns only.  A skipped lane adds
 nothing to the online softmax, so on ragged batches both kernels, bf16 and
 int8, stay bit-equal to their oracles, which walk every lane; and a page
 that only dead lanes name is never read: NaN there leaves the output
-finite and unchanged."""
+finite and unchanged.  The kernels take the whole lane-dense store and a
+layer index: NaN in every other layer leaves the output unchanged too,
+for one query head per KV head (g = 1), grouped heads (g > 1) and a
+single KV head, and with KV heads that share a 128-lane tile."""
 
 import jax
 import jax.numpy as jnp
@@ -33,9 +37,9 @@ CHUNK_ROWS = ((0, 10), (3, 13), (8, 8), (8, LANES * PS), (5, 2 * PS + 1),
               (1, 4 * PS))                    # (new_lens, cache_len)
 
 
-def _pages(rng, dtype):
-    k = rng.standard_normal((N_PAGES, PS, KVH, HD)).astype(np.float32)
-    v = rng.standard_normal((N_PAGES, PS, KVH, HD)).astype(np.float32)
+def _pages(rng, dtype, kvh=KVH):
+    k = rng.standard_normal((N_PAGES, PS, kvh, HD)).astype(np.float32)
+    v = rng.standard_normal((N_PAGES, PS, kvh, HD)).astype(np.float32)
     return jnp.asarray(k, dtype), jnp.asarray(v, dtype)
 
 
@@ -58,15 +62,15 @@ def _dead_pages(pi, live):
     return sorted(({0} | {int(x) for x in pi[pi >= 0]}) - named_live)
 
 
-def _decode(rng):
-    q = jnp.asarray(rng.standard_normal((len(DEC_LENS), H, HD)),
+def _decode(rng, h=H):
+    q = jnp.asarray(rng.standard_normal((len(DEC_LENS), h, HD)),
                     jnp.bfloat16)
     pi = _tables(rng, DEC_HELD)
     return q, pi, np.asarray(DEC_LENS, np.int32)
 
 
-def _chunk(rng):
-    q = jnp.asarray(rng.standard_normal((len(CHUNK_ROWS), S, H, HD)),
+def _chunk(rng, h=H):
+    q = jnp.asarray(rng.standard_normal((len(CHUNK_ROWS), S, h, HD)),
                     jnp.bfloat16)
     nl = np.asarray([r[0] for r in CHUNK_ROWS], np.int32)
     cl = np.asarray([r[1] for r in CHUNK_ROWS], np.int32)
@@ -74,47 +78,62 @@ def _chunk(rng):
     return q, pi, cl, nl
 
 
-def _run_decode(kind, q, k, v, pi, cl, lps):
+def _run_decode(kind, q, k, v, pi, cl, lps, layer=0):
+    ly = jnp.int32(layer)
     if kind == "int8":
         kq, ks = k
         vq, vs = v
-        out = _paged_attn_quant_call(q, kq, vq, ks, vs, pi, cl,
+        out = _paged_attn_quant_call(q, kq, vq, ks, vs, ly, pi, cl,
                                      interpret=INTERPRET, lanes_per_step=lps)
-        return out, jax.jit(R.paged_attn_quant_ref)(q, kq, vq, ks, vs, pi,
-                                                    cl)
-    out = _paged_attn_call(q, k, v, pi, cl, interpret=INTERPRET,
+        return out, jax.jit(R.paged_attn_quant_ref)(q, kq, vq, ks, vs, ly,
+                                                    pi, cl)
+    out = _paged_attn_call(q, k, v, ly, pi, cl, interpret=INTERPRET,
                            lanes_per_step=lps)
-    return out, jax.jit(R.paged_attn_ref)(q, k, v, pi, cl)
+    return out, jax.jit(R.paged_attn_ref)(q, k, v, ly, pi, cl)
 
 
-def _run_chunk(kind, q, k, v, pi, cl, nl, bq):
+def _run_chunk(kind, q, k, v, pi, cl, nl, bq, layer=0):
+    ly = jnp.int32(layer)
     ref = jax.jit(R.paged_chunk_attn_quant_ref if kind == "int8"
                   else R.paged_chunk_attn_ref, static_argnames="block_q")
     if kind == "int8":
         kq, ks = k
         vq, vs = v
-        out = _chunk_attn_quant_call(q, kq, vq, ks, vs, pi, cl, nl,
+        out = _chunk_attn_quant_call(q, kq, vq, ks, vs, ly, pi, cl, nl,
                                      interpret=INTERPRET, block_q=bq)
-        return out, ref(q, kq, vq, ks, vs, pi, cl, nl, block_q=bq)
-    out = _chunk_attn_call(q, k, v, pi, cl, nl, interpret=INTERPRET,
+        return out, ref(q, kq, vq, ks, vs, ly, pi, cl, nl, block_q=bq)
+    out = _chunk_attn_call(q, k, v, ly, pi, cl, nl, interpret=INTERPRET,
                            block_q=bq)
-    return out, ref(q, k, v, pi, cl, nl, block_q=bq)
+    return out, ref(q, k, v, ly, pi, cl, nl, block_q=bq)
 
 
-def _store(kind, rng):
-    """-> (k, v) as the kernel takes them: bf16 pages, or int8 pages with
-    their float32 scales."""
-    k, v = _pages(rng, jnp.bfloat16 if kind == "bf16" else jnp.float32)
+def _store(kind, rng, kvh=KVH):
+    """-> (k, v) as the kernel takes them, a one-layer lane-dense store:
+    bf16 pages, or int8 pages with their float32 scales."""
+    k, v = _pages(rng, jnp.bfloat16 if kind == "bf16" else jnp.float32, kvh)
     if kind == "int8":
-        return quantize_pages(k), quantize_pages(v)
-    return k, v
+        return tuple(tuple(map(R.one_layer, quantize_pages(x)))
+                     for x in (k, v))
+    return R.one_layer(k), R.one_layer(v)
 
 
 def _poison(kind, x, dead):
     """NaN into the dead pages (their scales, for int8 pages)."""
     if kind == "int8":
-        return x[0], x[1].at[jnp.asarray(dead)].set(jnp.nan)
-    return x.at[jnp.asarray(dead)].set(jnp.nan)
+        return x[0], x[1].at[:, jnp.asarray(dead)].set(jnp.nan)
+    return x.at[:, jnp.asarray(dead)].set(jnp.nan)
+
+
+def _deepen(kind, x, layer, depth=3):
+    """A ``depth``-layer store that holds ``x``'s one layer at ``layer``
+    and NaN in every other layer (NaN scales and arbitrary bytes, for
+    int8 pages)."""
+    if kind == "int8":
+        return (jnp.concatenate([x[0]] * depth),
+                jnp.full((depth,) + x[1].shape[1:], jnp.nan,
+                         x[1].dtype).at[layer].set(x[1][0]))
+    return jnp.full((depth,) + x.shape[1:], jnp.nan,
+                    x.dtype).at[layer].set(x[0])
 
 
 @pytest.mark.parametrize("lps", [1, 2, 4])
@@ -179,3 +198,36 @@ def test_chunk_never_reads_a_dead_lane(kind):
     out = np.asarray(out)
     assert np.all(np.isfinite(out))
     assert np.array_equal(out, np.asarray(clean))
+
+
+# (H, KVH): one query head per KV head, grouped heads, one KV head, and
+# grouped heads whose KV heads share 128-lane tiles (8 x 16 lanes)
+HEADS = {"g1": (4, 4), "g2": (4, 2), "kvh1": (4, 1), "tiled": (16, 8)}
+
+
+@pytest.mark.parametrize("heads", sorted(HEADS))
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_reads_only_its_layer(step, kind, layer, heads):
+    """The first and the last layer of a 3-layer store, every other layer
+    NaN: the output is finite, equal to the one-layer store's and bit-equal
+    to the oracle's."""
+    h, kvh = HEADS[heads]
+    rng = np.random.default_rng(18)
+    k, v = _store(kind, rng, kvh)
+    deep = _deepen(kind, k, layer), _deepen(kind, v, layer)
+    if step == "decode":
+        q, pi, cl = _decode(rng, h)
+        args = (jnp.asarray(pi), jnp.asarray(cl), 2)
+        flat, _ = _run_decode(kind, q, k, v, *args)
+        out, ref = _run_decode(kind, q, *deep, *args, layer=layer)
+    else:
+        q, pi, cl, nl = _chunk(rng, h)
+        args = (*map(jnp.asarray, (pi, cl, nl)), 4)
+        flat, _ = _run_chunk(kind, q, k, v, *args)
+        out, ref = _run_chunk(kind, q, *deep, *args, layer=layer)
+    out = np.asarray(out)
+    assert np.all(np.isfinite(out))
+    assert np.array_equal(out, np.asarray(flat))
+    assert np.array_equal(out, np.asarray(ref))

@@ -74,20 +74,20 @@ def test_dense_reference_would_fail_the_lint():
     rng = np.random.default_rng(0)
     b, lanes, ps, kvh, hd, sq = 2, 4, 8, 2, 16, 8
     q = jnp.asarray(rng.normal(size=(b, sq, 4, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(16, ps, kvh, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(16, ps, kvh, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(1, 16, ps, kvh * hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(1, 16, ps, kvh * hd)), jnp.float32)
     pi = jnp.asarray(rng.integers(0, 16, size=(b, lanes)), jnp.int32)
     cl = jnp.zeros((b,), jnp.int32)
     nl = jnp.full((b,), sq, jnp.int32)
     lowered = jax.jit(R.paged_chunk_dense_ref).lower(
-        q, kp, vp, pi, cl, nl).as_text()
+        q, kp, vp, jnp.int32(0), pi, cl, nl).as_text()
     fs = LH.lint_step("dense_ref", lowered,
                       forbid_shapes=[(b, lanes * ps, kvh, hd)])
     assert [f.rule for f in fs] == ["dense-kv-materialization"]
 
 
 def test_fp32_materialization_rule_has_teeth():
-    """Dequantizing the whole pool up front DOES build the fp32 twin of
+    """Dequantizing a layer's pages up front DOES build the fp32 twin of
     the int8 page store — proves the fp32-page rule catches exactly the
     shortcut the quant kernels exist to avoid."""
     import jax.numpy as jnp
@@ -96,7 +96,32 @@ def test_fp32_materialization_rule_has_teeth():
     n_pages, ps, kvh, hd = 16, 8, 2, 16
     kq = jnp.zeros((n_pages, ps, kvh, hd), jnp.int8)
     ks = jnp.ones((n_pages, kvh), jnp.float32)
-    lowered = jax.jit(Q.dequantize_pages).lower(kq, ks).as_text()
+    lowered = jax.jit(lambda q, s: Q.dequantize_pages(q, s).reshape(
+        n_pages, ps, kvh * hd)).lower(kq, ks).as_text()
     fs = LH.lint_step("deq_all", lowered,
-                      forbid_fp32_shapes=[(n_pages, ps, kvh, hd)])
+                      forbid_fp32_shapes=[(n_pages, ps, kvh * hd)])
+    assert [f.rule for f in fs] == ["fp32-page-materialization"]
+
+
+@pytest.mark.parametrize("step", ["decode_paged_quant",
+                                  "prefill_paged_quant"])
+def test_planted_fp32_store_trips_the_lint(steps, step):
+    """A quantized step that upcasts the whole lane-dense store to float32
+    before running still trips ``fp32-page-materialization`` under the
+    shapes the serving-step lint forbids: the guard has not gone silent
+    with the store's new layout."""
+    import jax.numpy as jnp
+    from repro import configs
+    from repro.models import model as M
+
+    cfg = configs.get_smoke("llama3.2-1b")
+    store = M.init_paged_caches(cfg, 16, 8, quantized=True)
+
+    def upcast(store):
+        return {k: x.astype(jnp.float32) * 2 for k, x in store.items()}
+
+    lowered = jax.jit(upcast).lower(store).as_text()
+    forbid = steps[step]["forbid_fp32_shapes"]
+    assert LH.pool_fp32_shapes(cfg, 16, 8) == forbid
+    fs = LH.lint_step("planted", lowered, forbid_fp32_shapes=forbid)
     assert [f.rule for f in fs] == ["fp32-page-materialization"]

@@ -59,7 +59,9 @@ def _random_chunk_case(rng, b, s, h, kvh, hd, n_pages, ps, lanes,
         off += npg
         cache_len[i] = clen
         new_lens[i] = nl
-    return q, kp, vp, map(jnp.asarray, (page_idx, cache_len, new_lens))
+    # a one-layer store in the store's lane-dense layout, and its layer
+    return (q, R.one_layer(kp), R.one_layer(vp), jnp.int32(0),
+            map(jnp.asarray, (page_idx, cache_len, new_lens)))
 
 
 def test_chunk_kernel_bit_exact_vs_ref():
@@ -67,11 +69,11 @@ def test_chunk_kernel_bit_exact_vs_ref():
     q-block, page) walk, both under jit), with mid-prompt chunks, partial
     chunks and fully padded rows in one batch."""
     rng = np.random.default_rng(0)
-    q, kp, vp, (pi, cl, nl) = _random_chunk_case(
+    q, kp, vp, ly, (pi, cl, nl) = _random_chunk_case(
         rng, b=5, s=8, h=8, kvh=2, hd=16, n_pages=32, ps=4, lanes=6)
-    out_k = np.asarray(K.paged_chunk_attention(q, kp, vp, pi, cl, nl))
-    out_r = np.asarray(jax.jit(R.paged_chunk_attn_ref)(q, kp, vp, pi, cl,
-                                                       nl))
+    out_k = np.asarray(K.paged_chunk_attention(q, kp, vp, ly, pi, cl, nl))
+    out_r = np.asarray(jax.jit(R.paged_chunk_attn_ref)(q, kp, vp, ly, pi,
+                                                       cl, nl))
     assert np.array_equal(out_k, out_r)
     assert np.array_equal(out_k[-1], np.zeros_like(out_k[-1]))  # pad row
 
@@ -81,11 +83,11 @@ def test_chunk_kernel_matches_dense_gather():
     materialized pages), up to float tolerance — the two sides of the
     benchmark's streamed-vs-dense comparison agree."""
     rng = np.random.default_rng(1)
-    q, kp, vp, (pi, cl, nl) = _random_chunk_case(
+    q, kp, vp, ly, (pi, cl, nl) = _random_chunk_case(
         rng, b=4, s=6, h=4, kvh=2, hd=8, n_pages=16, ps=4, lanes=4)
-    out_k = np.asarray(K.paged_chunk_attention(q, kp, vp, pi, cl, nl))
-    dense = np.asarray(jax.jit(R.paged_chunk_dense_ref)(q, kp, vp, pi, cl,
-                                                        nl))
+    out_k = np.asarray(K.paged_chunk_attention(q, kp, vp, ly, pi, cl, nl))
+    dense = np.asarray(jax.jit(R.paged_chunk_dense_ref)(q, kp, vp, ly, pi,
+                                                        cl, nl))
     assert np.allclose(out_k, dense, atol=1e-5)
 
 
@@ -93,12 +95,12 @@ def test_chunk_kernel_multi_qblock_grid():
     """A chunk wider than the q-block limit spans several q-blocks in the
     grid and still matches the oracle bit for bit."""
     rng = np.random.default_rng(2)
-    q, kp, vp, (pi, cl, nl) = _random_chunk_case(
+    q, kp, vp, ly, (pi, cl, nl) = _random_chunk_case(
         rng, b=2, s=64, h=4, kvh=2, hd=8, n_pages=64, ps=8, lanes=10,
         pad_rows=0)
-    out_k = np.asarray(K.paged_chunk_attention(q, kp, vp, pi, cl, nl))
-    out_r = np.asarray(jax.jit(R.paged_chunk_attn_ref)(q, kp, vp, pi, cl,
-                                                       nl))
+    out_k = np.asarray(K.paged_chunk_attention(q, kp, vp, ly, pi, cl, nl))
+    out_r = np.asarray(jax.jit(R.paged_chunk_attn_ref)(q, kp, vp, ly, pi,
+                                                       cl, nl))
     assert np.array_equal(out_k, out_r)
 
 

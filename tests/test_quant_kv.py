@@ -97,6 +97,23 @@ if HAVE_HYPOTHESIS:
 # Quantized kernels vs oracles
 # ---------------------------------------------------------------------------
 
+L0 = jnp.int32(0)                 # the one layer of a one-layer store
+
+
+def _quant_store(x):
+    """(n_pages, ps, KVH, hd) float pages -> a one-layer int8 store leaf
+    (1, n_pages, ps, KVH * hd) and its scales (1, n_pages, KVH)."""
+    return tuple(map(R.one_layer, quantize_pages(x)))
+
+
+def _dequant_layer(q, s, kvh, layer=0):
+    """One layer of an int8 store leaf back to (n_pages, ps, KVH, hd)
+    float32."""
+    _, n, ps, lanes = q.shape
+    return dequantize_pages(q[layer].reshape(n, ps, kvh, lanes // kvh),
+                            s[layer])
+
+
 
 def _decode_case(seed, b=4, h=4, kvh=2, hd=16, n_pages=32, ps=4, lanes=6):
     r = np.random.default_rng(seed)
@@ -119,12 +136,12 @@ def test_decode_quant_kernel_bit_exact_vs_quant_oracle():
     """At lanes_per_step=1 the quantized decode kernel equals the quant
     oracle bit for bit (same dequant op order, both under jit)."""
     q, k, v, pi, cl = _decode_case(2)
-    kq, ks = quantize_pages(k)
-    vq, vs = quantize_pages(v)
-    out = _paged_attn_quant_call(q, kq, vq, ks, vs, pi, cl,
+    kq, ks = _quant_store(k)
+    vq, vs = _quant_store(v)
+    out = _paged_attn_quant_call(q, kq, vq, ks, vs, L0, pi, cl,
                                  interpret=jax.default_backend() != "tpu",
                                  lanes_per_step=1)
-    ref = jax.jit(R.paged_attn_quant_ref)(q, kq, vq, ks, vs, pi, cl)
+    ref = jax.jit(R.paged_attn_quant_ref)(q, kq, vq, ks, vs, L0, pi, cl)
     assert np.array_equal(np.asarray(out), np.asarray(ref))
 
 
@@ -135,11 +152,12 @@ def test_decode_quant_kernel_within_bound_of_fp32():
     per-element V dequant error plus the score-shift term — 0.05 is the
     gated envelope at unit-variance inputs."""
     q, k, v, pi, cl = _decode_case(3)
-    kq, ks = quantize_pages(k)
-    vq, vs = quantize_pages(v)
-    out = K.paged_attention_quant(q, kq, vq, ks, vs, pi, cl)
-    ref32 = jax.jit(R.paged_attn_ref)(q, k, v, pi, cl)
-    qref = jax.jit(R.paged_attn_quant_ref)(q, kq, vq, ks, vs, pi, cl)
+    kq, ks = _quant_store(k)
+    vq, vs = _quant_store(v)
+    out = K.paged_attention_quant(q, kq, vq, ks, vs, L0, pi, cl)
+    ref32 = jax.jit(R.paged_attn_ref)(q, R.one_layer(k), R.one_layer(v), L0,
+                                      pi, cl)
+    qref = jax.jit(R.paged_attn_quant_ref)(q, kq, vq, ks, vs, L0, pi, cl)
     # wrapper may run a tuned lanes_per_step: few-ulp vs the quant oracle
     assert np.allclose(np.asarray(out), np.asarray(qref), atol=1e-5)
     assert np.max(np.abs(np.asarray(out) - np.asarray(ref32))) < 0.05
@@ -165,17 +183,18 @@ def test_chunk_quant_kernel_bit_exact_vs_quant_oracle():
         pi[i, :npg] = perm[off:off + npg]
         off += npg
     pi, cl, nl = map(jnp.asarray, (pi, cl, nl))
-    kq, ks = quantize_pages(k)
-    vq, vs = quantize_pages(v)
-    out = _chunk_attn_quant_call(q, kq, vq, ks, vs, pi, cl, nl,
+    kq, ks = _quant_store(k)
+    vq, vs = _quant_store(v)
+    out = _chunk_attn_quant_call(q, kq, vq, ks, vs, L0, pi, cl, nl,
                                  interpret=jax.default_backend() != "tpu",
                                  block_q=0)
-    ref = jax.jit(R.paged_chunk_attn_quant_ref)(q, kq, vq, ks, vs, pi, cl,
-                                                nl)
+    ref = jax.jit(R.paged_chunk_attn_quant_ref)(q, kq, vq, ks, vs, L0, pi,
+                                                cl, nl)
     assert np.array_equal(np.asarray(out), np.asarray(ref))
     assert np.array_equal(np.asarray(out)[-1],
                           np.zeros_like(np.asarray(out)[-1]))
-    ref32 = jax.jit(R.paged_chunk_attn_ref)(q, k, v, pi, cl, nl)
+    ref32 = jax.jit(R.paged_chunk_attn_ref)(q, R.one_layer(k),
+                                            R.one_layer(v), L0, pi, cl, nl)
     assert np.max(np.abs(np.asarray(out) - np.asarray(ref32))) < 0.05
 
 
@@ -190,9 +209,9 @@ def test_requant_scatter_touches_only_new_token_pages():
     is rewritten.  This is the byte-level COW contract."""
     r = np.random.default_rng(5)
     n_pages, ps, kvh, hd, lanes = 16, 4, 2, 8, 6
-    kq, ks = quantize_pages(
+    kq, ks = _quant_store(
         jnp.asarray(r.standard_normal((n_pages, ps, kvh, hd)), jnp.float32))
-    vq, vs = quantize_pages(
+    vq, vs = _quant_store(
         jnp.asarray(r.standard_normal((n_pages, ps, kvh, hd)), jnp.float32))
     b, s = 2, 4
     k_new = jnp.asarray(r.standard_normal((b, s, kvh, hd)), jnp.float32)
@@ -207,20 +226,20 @@ def test_requant_scatter_touches_only_new_token_pages():
                                          pages, cache_len, new_lens)
     touched = {2, 7, 8}                     # pages holding new tokens
     for p in range(n_pages):
-        same = (bool(jnp.array_equal(kq[p], kq2[p]))
-                and bool(jnp.array_equal(ks[p], ks2[p]))
-                and bool(jnp.array_equal(vq[p], vq2[p]))
-                and bool(jnp.array_equal(vs[p], vs2[p])))
+        same = (bool(jnp.array_equal(kq[0, p], kq2[0, p]))
+                and bool(jnp.array_equal(ks[0, p], ks2[0, p]))
+                and bool(jnp.array_equal(vq[0, p], vq2[0, p]))
+                and bool(jnp.array_equal(vs[0, p], vs2[0, p])))
         assert same == (p not in touched), (p, same)
     # the new rows decode back within the round-trip bound
-    dk = dequantize_pages(kq2, ks2)
+    dk = _dequant_layer(kq2, ks2, kvh)
     for i, (c, n) in enumerate(((12, 4), (17, 4))):
         for j in range(n):
             t = c - n + j
             lane, off = t // ps, t % ps
             page = int(pages[i, lane])
             err = jnp.max(jnp.abs(dk[page, off] - k_new[i, j]))
-            assert float(err) <= float(ks2[page].max()) / 2 + 1e-6
+            assert float(err) <= float(ks2[0, page].max()) / 2 + 1e-6
 
 
 def test_requant_scatter_matches_explicit_requant():
@@ -229,9 +248,9 @@ def test_requant_scatter_matches_explicit_requant():
     boundary mid-chunk."""
     r = np.random.default_rng(6)
     n_pages, ps, kvh, hd = 8, 4, 2, 8
-    kq, ks = quantize_pages(
+    kq, ks = _quant_store(
         jnp.asarray(r.standard_normal((n_pages, ps, kvh, hd)), jnp.float32))
-    vq, vs = quantize_pages(
+    vq, vs = _quant_store(
         jnp.asarray(r.standard_normal((n_pages, ps, kvh, hd)), jnp.float32))
     k_new = jnp.asarray(r.standard_normal((1, 3, kvh, hd)), jnp.float32)
     v_new = jnp.asarray(r.standard_normal((1, 3, kvh, hd)), jnp.float32)
@@ -243,12 +262,12 @@ def test_requant_scatter_matches_explicit_requant():
     # lane 1 (page 5): slots 0..2 = new tokens 4..6, slot 3 zeroed
     buf = jnp.zeros((ps, kvh, hd), jnp.float32)
     buf = buf.at[0:3].set(k_new[0])
-    want_q, want_s = quantize_pages(buf[None])
-    assert bool(jnp.array_equal(kq2[5], want_q[0]))
-    assert bool(jnp.array_equal(ks2[5], want_s[0]))
+    want_q, want_s = _quant_store(buf[None])
+    assert bool(jnp.array_equal(kq2[0, 5], want_q[0, 0]))
+    assert bool(jnp.array_equal(ks2[0, 5], want_s[0, 0]))
     # lane 0 (page 2, fully old): untouched — it holds no new token
-    assert bool(jnp.array_equal(kq2[2], kq[2]))
-    assert bool(jnp.array_equal(vq2[2], vq[2]))
+    assert bool(jnp.array_equal(kq2[0, 2], kq[0, 2]))
+    assert bool(jnp.array_equal(vq2[0, 2], vq[0, 2]))
 
 
 def test_requant_scatter_full_page_bit_stable():
@@ -258,15 +277,46 @@ def test_requant_scatter_full_page_bit_stable():
     r = np.random.default_rng(7)
     n_pages, ps, kvh, hd = 4, 4, 2, 8
     x = jnp.asarray(r.standard_normal((n_pages, ps, kvh, hd)), jnp.float32)
-    kq, ks = quantize_pages(x)
-    vq, vs = quantize_pages(x)
-    full = dequantize_pages(kq, ks)
+    kq, ks = _quant_store(x)
+    vq, vs = _quant_store(x)
+    full = _dequant_layer(kq, ks, kvh)
     pages = jnp.asarray([[1, -1]], jnp.int32)
     kq2, vq2, ks2, vs2 = requant_scatter(
         kq, vq, ks, vs, full[1][None], full[1][None], pages,
         jnp.asarray([ps], jnp.int32), jnp.asarray([ps], jnp.int32))
     assert bool(jnp.array_equal(kq2, kq))
     assert bool(jnp.array_equal(ks2, ks))
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_requant_scatter_touches_only_its_layer(layer):
+    """Of a 3-layer store, only ``layer``'s touched pages change, and
+    exactly as the same write into that layer alone changes them: every
+    other layer keeps its bytes and scales bit for bit."""
+    r = np.random.default_rng(8)
+    n_pages, ps, kvh, hd = 8, 4, 2, 8
+
+    def store():
+        q, s = zip(*(_quant_store(jnp.asarray(
+            r.standard_normal((n_pages, ps, kvh, hd)), jnp.float32))
+            for _ in range(3)))
+        return jnp.concatenate(q), jnp.concatenate(s)
+
+    (kq, ks), (vq, vs) = store(), store()
+    k_new = jnp.asarray(r.standard_normal((1, 3, kvh, hd)), jnp.float32)
+    v_new = jnp.asarray(r.standard_normal((1, 3, kvh, hd)), jnp.float32)
+    pages = jnp.asarray([[2, 5, 3, -1]], jnp.int32)
+    cache_len, new_lens = jnp.asarray([7], jnp.int32), jnp.asarray([3])
+    out = requant_scatter(kq, vq, ks, vs, k_new, v_new, pages, cache_len,
+                          new_lens, jnp.int32(layer))
+    alone = requant_scatter(kq[layer:layer + 1], vq[layer:layer + 1],
+                            ks[layer:layer + 1], vs[layer:layer + 1], k_new,
+                            v_new, pages, cache_len, new_lens, L0)
+    for before, after, one in zip((kq, vq, ks, vs), out, alone):
+        for other in {0, 1, 2} - {layer}:
+            assert bool(jnp.array_equal(after[other], before[other]))
+        assert bool(jnp.array_equal(after[layer], one[0]))
+        assert not bool(jnp.array_equal(after[layer], before[layer]))
 
 
 # ---------------------------------------------------------------------------

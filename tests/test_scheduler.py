@@ -82,8 +82,9 @@ def test_paged_attn_kernel_bit_exact_vs_ref():
         cache_len[i] = int(rng.integers(1, npg * ps + 1))
     cache_len[3] = 0                       # inactive slot -> zeros out
     pi, cl = jnp.asarray(page_idx), jnp.asarray(cache_len)
-    out_k = np.asarray(K.paged_attention(q, kp, vp, pi, cl))
-    out_r = np.asarray(jax.jit(R.paged_attn_ref)(q, kp, vp, pi, cl))
+    store = R.one_layer(kp), R.one_layer(vp), jnp.int32(0)
+    out_k = np.asarray(K.paged_attention(q, *store, pi, cl))
+    out_r = np.asarray(jax.jit(R.paged_attn_ref)(q, *store, pi, cl))
     assert np.array_equal(out_k, out_r)    # bit-exact, same page-walk order
     assert np.array_equal(out_k[3], np.zeros_like(out_k[3]))
 
@@ -101,8 +102,9 @@ def test_paged_attn_matches_dense_softmax():
     page_idx = np.asarray([[0, 1, 2, 3], [4, 5, -1, -1], [6, -1, -1, -1]],
                           np.int32)
     cache_len = np.asarray([15, 7, 3], np.int32)
-    out = np.asarray(K.paged_attention(q, kp, vp, jnp.asarray(page_idx),
-                                       jnp.asarray(cache_len)))
+    out = np.asarray(K.paged_attention(
+        q, R.one_layer(kp), R.one_layer(vp), jnp.int32(0),
+        jnp.asarray(page_idx), jnp.asarray(cache_len)))
     kd = np.zeros((b, lanes * ps, kvh, hd), np.float32)
     vd = np.zeros((b, lanes * ps, kvh, hd), np.float32)
     for i in range(b):

@@ -151,3 +151,79 @@ def test_logical_to_spec_drops_missing_axes():
     mesh = Mesh(devs, ("data", "model"))
     spec = logical_to_spec(MeshRules(), mesh, ("batch", "model", "fsdp"))
     assert spec == P(("data",), "model", "data")
+
+
+PAGED_TP = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import sys
+    sys.path.insert(0, {src!r})
+    import dataclasses, json
+    import jax, jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from repro import configs
+    from repro.dist.sharding import MeshRules, page_store_specs
+    from repro.models import model as M
+    from repro.serving.steps import make_decode_step, make_paged_prefill_step
+
+    toks = jnp.asarray(np.random.default_rng(0).integers(0, 256, (2, 8)),
+                       jnp.int32)
+    pages = jnp.asarray([[1, 2, 3, -1], [5, 6, -1, -1]], jnp.int32)
+    gaps = {{}}
+    for kvh in (4, 1):
+        cfg = dataclasses.replace(configs.get_smoke("llama3.2-1b"),
+                                  n_heads=8, n_kv_heads=kvh)
+        params = M.init_params(jax.random.PRNGKey(0), cfg)
+        for quant in (False, True):
+            out = []
+            for tp in (1, 4):
+                mesh = Mesh(np.array(jax.devices()[:tp]).reshape(1, tp),
+                            ("data", "model"))
+                store = M.init_paged_caches(cfg, 16, 4, quantized=quant)
+                specs = page_store_specs(store, kvh, mesh)
+                store = {{k: jax.device_put(x, NamedSharding(mesh, specs[k]))
+                         for k, x in store.items()}}
+                prefill = jax.jit(make_paged_prefill_step(cfg, mesh,
+                                                          MeshRules()))
+                decode = jax.jit(make_decode_step(cfg, mesh, MeshRules(),
+                                                  paged=True))
+                nxt, last, store = prefill(
+                    params, store, toks, jnp.asarray([8, 6]),
+                    jnp.asarray([8, 6]), pages)
+                _, logits, store = decode(params, store, nxt[:, None],
+                                          jnp.asarray([9, 7]), pages)
+                out.append((np.asarray(last), np.asarray(logits),
+                            {{k: np.asarray(x, np.float32)
+                             for k, x in store.items()}}))
+            (l1, d1, s1), (l4, d4, s4) = out
+            gaps[f"{{kvh}}-{{quant}}"] = {{
+                "prefill": float(np.abs(l1 - l4).max()),
+                "decode": float(np.abs(d1 - d4).max()),
+                "store": max(float(np.abs(s1[k] - s4[k]).max())
+                             for k in s1)}}
+    print(json.dumps(gaps))
+""")
+
+
+@pytest.fixture(scope="module")
+def paged_tp_gaps():
+    """Largest gaps between a (1, 4) mesh and one device, per (KV heads,
+    int8 store), over one paged prefill and one paged decode step."""
+    code = PAGED_TP.format(src=os.path.abspath(SRC))
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("quant", [False, True])
+@pytest.mark.parametrize("kvh", [4, 1])
+def test_paged_steps_split_the_store_over_model(paged_tp_gaps, kvh, quant):
+    """On a (1, 4) mesh the lane-dense store splits its lanes over
+    ``model`` (4 KV heads: one per shard) or replicates (one KV head), and
+    the paged prefill and decode steps give what one device gives, up to
+    bf16 rounding of the differently split matmuls."""
+    gap = paged_tp_gaps[f"{kvh}-{quant}"]
+    assert gap["prefill"] < 0.05 and gap["decode"] < 0.05, gap
+    assert gap["store"] < 0.05, gap
